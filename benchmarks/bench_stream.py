@@ -366,7 +366,8 @@ def main(out_json: str | None = "BENCH_stream.json") -> None:
         )
     contained = inj2.report().contained
 
-    # modeled roofline of the fused pass under the final plan (R relation)
+    # modeled roofline of the fused pass under the final plan (R relation),
+    # on the chip the kernel targets: a model, so no device is asked
     rel = query.relations[0]
     profile = overlap_profile(
         n_rows=n_r,
@@ -376,6 +377,7 @@ def main(out_json: str | None = "BENCH_stream.json") -> None:
         n_sketch_cols=1,
         depth=fused.config.sketch_depth,
         width=fused.config.sketch_width,
+        device_kind="TPU v5 lite",
         block=fused.config.fused_block,
     )
 

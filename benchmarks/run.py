@@ -17,6 +17,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (
         bench_2way,
         bench_2way_scaling,

@@ -16,7 +16,10 @@ from repro.core import (
     three_way_paper,
 )
 from repro.data import paper_3way
+from repro.launch.compile_cache import enable_compile_cache
 from repro.mapreduce import oracle_join, run_distributed, run_join
+
+enable_compile_cache()
 
 query = three_way_paper()
 print(f"query: {query}")

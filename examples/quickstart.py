@@ -10,7 +10,10 @@ import numpy as np
 
 from repro.core import plan_shares_skew, two_way
 from repro.data import paper_2way
+from repro.launch.compile_cache import enable_compile_cache
 from repro.mapreduce import naive_two_way, oracle_join, run_join
+
+enable_compile_cache()
 
 # 1. skewed data: |R| = 10 * |S|, one heavy hitter (B=7) in 10% of tuples
 rng = np.random.default_rng(0)

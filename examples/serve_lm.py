@@ -15,7 +15,10 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.models import build_model
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import BucketServer, Request
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--arch", default="olmo-1b")

@@ -47,6 +47,7 @@ import sys
 import numpy as np
 
 from repro.core import two_way
+from repro.launch.compile_cache import enable_compile_cache
 from repro.mapreduce import oracle_join
 from repro.stream import (
     MultiQueryEngine,
@@ -138,6 +139,7 @@ def multi_query_demo(n_queries: int, trace: str | None = None) -> int:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument(
         "--ckpt-dir",

@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.data.pipeline import TokenPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.train import (
     AsyncCheckpointer,
@@ -45,6 +46,7 @@ PRESETS = {
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b")
     ap.add_argument("--preset", default="tiny", choices=sorted(PRESETS))

@@ -22,13 +22,18 @@ tuple blocks:
     is then a pure precomputed-index scatter: the send buffers pack with no
     sort, no searchsorted, and no data-dependent control flow.
 
-Input streaming: rows are consumed block-by-block from HBM with
-double-buffered ``make_async_copy`` DMA into VMEM scratch, so the next
-block's DMA overlaps the current block's VPU compute (DESIGN.md §7 gives
-the roofline; ``overlap_profile`` models it).  ``double_buffer=False``
-falls back to the automatic grid pipeline, which performs the same
-double-buffering implicitly.  Both variants run under interpret mode on
-CPU, which is what CI exercises against the oracle.
+Layout: every in-kernel value is 2-D.  A tuple block is ``[B, arity+1]``
+(the last column is the validity mask); each attribute and each emission
+column is a ``[B, 1]`` static lane slice, and the rank window is one MXU
+product per block (``_write_ranks``), so no intermediate grows with the
+route width squared.  The grid walks the blocks in order; ``dest``/``rank``
+leave VMEM block by block through their ``BlockSpec``, while ``counts`` and
+the sketch table stay resident and accumulate — VMEM use is independent of
+N.  Input streaming: with ``double_buffer=True`` rows stay in HBM and two
+VMEM slots are filled by ``make_async_copy``, the copy of block i+1
+overlapping the compute on block i; ``double_buffer=False`` lets the grid
+pipeline do the same double buffering implicitly.  Both variants run under
+interpret mode on CPU, which is what CI exercises against the oracle.
 """
 from __future__ import annotations
 
@@ -52,172 +57,265 @@ from repro.mapreduce.hashing import mix32_jnp as _mix32
 #   excludes:((col, (value, ...)), ...)        ordinary-type HH exclusions
 RouteTable = tuple
 
+# rank-window counts go through the MXU in bf16, which is exact for the
+# per-row arrival counts (<= route width) only up to 256
+MAX_ROUTE_WIDTH = 256
+# the [k_pad, B] one-hot planes of the rank window are kept at <= 1 MiB
+_ONEHOT_WORDS = 256 * 1024
+
 
 def route_width(routes: RouteTable) -> int:
     """Total emission width W = sum of per-residual replication."""
     return sum(len(rep) for _, _, rep, _, _ in routes)
 
 
-def _dest_block(rows, msk, routes: RouteTable):
-    """[B, W] destination ids for one tuple block (−1 = not emitted).
+def _round_up(n: int, m: int) -> int:
+    return ((n + m - 1) // m) * m
 
-    Mirrors ``mapreduce.keys.RouteSpec.destinations`` exactly, column
-    layout included (residual-major, replica-minor).
-    """
-    n = rows.shape[0]
-    blocks = []
+
+def _block_rows(block: int, k_pad: int) -> int:
+    """Tuples per grid step: a multiple of 128 (one lane tile), shrunk while
+    the [k_pad, B] one-hot planes exceed 1 MiB."""
+    block = _round_up(max(int(block), 1), 128)
+    while block > 128 and block * k_pad > _ONEHOT_WORDS:
+        block //= 2
+    return block
+
+
+def _dest_rows(cols, msk, routes: RouteTable):
+    """W destination rows ``[1, B]`` for one tuple block (−1 = not emitted).
+    Mirrors ``mapreduce.keys.RouteSpec.destinations`` exactly, column order
+    included (residual-major, replica-minor)."""
+    out = []
     for offset, hashed, rep, pins, excludes in routes:
-        base = jnp.full((n,), offset, jnp.int32)
+        base = jnp.full(msk.shape, offset, jnp.int32)
         for col, seed, dim, stride in hashed:
-            bucket = (_mix32(rows[:, col], seed) % jnp.uint32(dim)).astype(
+            bucket = (_mix32(cols[col], seed) % jnp.uint32(dim)).astype(
                 jnp.int32
             )
             base = base + bucket * jnp.int32(stride)
         ok = msk
         for col, value in pins:
-            ok = ok & (rows[:, col] == value)
+            ok = ok & (cols[col] == value)
         for col, values in excludes:
-            v = rows[:, col]
-            bad = jnp.zeros((n,), bool)
             for hv in values:
-                bad = bad | (v == hv)
-            ok = ok & ~bad
+                ok = ok & (cols[col] != hv)
         for r_off in rep:
-            blocks.append(jnp.where(ok, base + jnp.int32(r_off), jnp.int32(-1)))
-    return jnp.stack(blocks, axis=1)
+            out.append(jnp.where(ok, base + jnp.int32(r_off), jnp.int32(-1)))
+    return out
 
 
-def _cms_block(rows, msk, sketch_cols, seeds, width):
-    """[n_cols*depth, width] Count-Min increment for one tuple block."""
-    n = rows.shape[0]
-    bins = jax.lax.broadcasted_iota(jnp.int32, (n, width), 1)
+def _dest_static(cols, msk, enc_ref, dest_ref, kiota, *, routes):
+    """Write the static route table's destination rows into ``dest_ref``;
+    returns the block's per-tuple destination histogram ``[k_pad, B]``."""
+    per_tuple = jnp.zeros(kiota.shape, jnp.int32)
+    for w, d in enumerate(_dest_rows(cols, msk, routes)):
+        dest_ref[pl.ds(w, 1), :] = d
+        per_tuple = per_tuple + (d == kiota).astype(jnp.int32)
+    return per_tuple
+
+
+def _cms_rows(cols, msk, sketch_cols, seeds, width):
+    """``[1, width]`` Count-Min increments, one per (column, seed) row.  The
+    one-hot plane is ``[width, B]``; its tuple-axis sum is an MXU product
+    with a ones operand (exact: counts <= B)."""
+    b = msk.shape[1]
+    bins = jax.lax.broadcasted_iota(jnp.int32, (width, b), 0)
+    ones = jnp.ones((8, b), jnp.bfloat16)
     out = []
     for col in sketch_cols:
-        vals = rows[:, col]
         for seed in seeds:
-            bucket = (_mix32(vals, seed) % jnp.uint32(width)).astype(jnp.int32)
-            onehot = (bucket[:, None] == bins) & msk[:, None]
-            out.append(onehot.astype(jnp.int32).sum(axis=0))
-    return jnp.stack(out)
+            bucket = (_mix32(cols[col], seed) % jnp.uint32(width)).astype(
+                jnp.int32
+            )
+            bucket = jnp.where(msk, bucket, jnp.int32(-1))  # padding: no bin
+            counts = jax.lax.dot_general(
+                ones, (bucket == bins).astype(jnp.bfloat16),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [8, width], all rows equal
+            out.append(jax.lax.slice_in_dim(counts, 0, 1).astype(jnp.int32))
+    return out
 
 
-def _rank_counts_block(dest, prev_counts, k_pad):
-    """(rank [B, W], counts_delta [k_pad]) for one block.
+def _write_ranks(dest_ref, rank_ref, per_tuple, prev_counts, kiota, w_out):
+    """Write each emission's arrival rank at its destination into
+    ``rank_ref``, in flat order ``b·W + w``: ``prev_counts [k_pad, 1]`` for
+    earlier blocks, plus earlier tuples of this block (one product of the
+    per-tuple histogram with a strictly-upper-triangular ``[B, B]``), plus
+    this tuple's earlier columns (a running one-hot sum).  No sort, no
+    scatter."""
+    b = per_tuple.shape[1]
+    upper = (
+        jax.lax.broadcasted_iota(jnp.int32, (b, b), 0)
+        < jax.lax.broadcasted_iota(jnp.int32, (b, b), 1)
+    ).astype(jnp.bfloat16)
+    before = jnp.dot(
+        per_tuple.astype(jnp.bfloat16), upper,
+        preferred_element_type=jnp.float32,
+    ).astype(jnp.int32) + prev_counts
 
-    rank = arrivals at this destination before this emission (earlier
-    blocks via ``prev_counts``, earlier flat positions in this block via a
-    dense order comparison — no sort, no scatter, VPU-only).
-    """
-    b, w = dest.shape
-    kiota = jax.lax.broadcasted_iota(jnp.int32, (b, w, k_pad), 2)
-    onehot = dest[:, :, None] == kiota  # invalid (−1) matches nothing
-    base = jnp.where(onehot, prev_counts[None, None, :], 0).sum(axis=2)
-    flat = (
-        jax.lax.broadcasted_iota(jnp.int32, (b, w), 0) * w
-        + jax.lax.broadcasted_iota(jnp.int32, (b, w), 1)
-    )
-    eq = dest[:, :, None, None] == dest[None, None, :, :]
-    earlier = flat[None, None, :, :] < flat[:, :, None, None]
-    rank_in_block = (eq & earlier).astype(jnp.int32).sum(axis=(2, 3))
-    rank = jnp.where(dest >= 0, base + rank_in_block, -1)
-    return rank, onehot.astype(jnp.int32).sum(axis=(0, 1))
+    def column(w, before):
+        d = dest_ref[pl.ds(w, 1), :]
+        hit = d == kiota  # invalid (−1) hits nothing
+        r = jnp.where(hit, before, 0).sum(axis=0, keepdims=True)
+        rank_ref[pl.ds(w, 1), :] = jnp.where(d >= 0, r, jnp.int32(-1))
+        return before + hit.astype(jnp.int32)
+
+    jax.lax.fori_loop(0, w_out, column, before)
 
 
-def _unpack_refs(out_refs, *, with_route, with_sketch):
-    refs = list(out_refs)
+def _ingest_kernel(
+    rows_in, *refs, dest_fn, has_enc, with_route, with_sketch, sketch_cols,
+    seeds, width, k_pad, w_out, arity, block, nsteps, double_buffer,
+):
+    """One grid step = one tuple block.  ``counts`` and the sketch table are
+    revisited every step and accumulate in VMEM; ``dest``/``rank`` blocks
+    are written back to HBM by the pipeline."""
+    refs = list(refs)
+    enc_ref = refs.pop(0) if has_enc else None
+    if double_buffer:
+        sem = refs.pop()
+        scratch = refs.pop()
     dest_ref = rank_ref = counts_ref = cms_ref = None
     if with_route:
         dest_ref, rank_ref, counts_ref = refs[:3]
-        refs = refs[3:]
     if with_sketch:
-        (cms_ref,) = refs
-    return dest_ref, rank_ref, counts_ref, cms_ref
+        cms_ref = refs[-1]
+    i = pl.program_id(0)
 
+    def dma(slot, j):
+        return pltpu.make_async_copy(
+            rows_in.at[:, pl.ds(j * block, block)],
+            scratch.at[slot],
+            sem.at[slot],
+        )
 
-def _fused_grid_kernel(
-    rows_ref, *out_refs, routes, sketch_cols, seeds, width, k_pad
-):
-    """Grid-pipelined variant: one step per tuple block; counts and the
-    sketch table are revisited every step and accumulate in VMEM."""
-    dest_ref, rank_ref, counts_ref, cms_ref = _unpack_refs(
-        out_refs, with_route=bool(routes), with_sketch=bool(sketch_cols)
-    )
-    step = pl.program_id(0)
-
-    @pl.when(step == 0)
+    @pl.when(i == 0)
     def _init():
         if counts_ref is not None:
             counts_ref[...] = jnp.zeros_like(counts_ref)
         if cms_ref is not None:
             cms_ref[...] = jnp.zeros_like(cms_ref)
+        if double_buffer:
+            dma(0, 0).start()
 
-    blk = rows_ref[...]  # [B, arity+1]; last column is the validity mask
-    rows, msk = blk[:, :-1], blk[:, -1] != 0
+    if double_buffer:
+        slot = i % 2
+
+        @pl.when(i + 1 < nsteps)
+        def _prefetch():
+            dma(1 - slot, i + 1).start()
+
+        dma(slot, i).wait()
+        blk = scratch[slot]
+    else:
+        blk = rows_in[...]
+    # [R, B]: one lane-dense row per attribute, then the validity mask
+    cols = [
+        jax.lax.slice_in_dim(blk, c, c + 1, axis=0) for c in range(arity + 1)
+    ]
+    rows, msk = cols[:-1], cols[-1] != 0
     if cms_ref is not None:
-        cms_ref[...] += _cms_block(rows, msk, sketch_cols, seeds, width)
+        for j, inc in enumerate(_cms_rows(rows, msk, sketch_cols, seeds, width)):
+            cms_ref[pl.ds(j, 1), :] += inc
     if dest_ref is not None:
-        dest = _dest_block(rows, msk, routes)
-        rank, delta = _rank_counts_block(dest, counts_ref[...], k_pad)
-        dest_ref[...] = dest
-        rank_ref[...] = rank
-        counts_ref[...] += delta
+        kiota = jax.lax.broadcasted_iota(jnp.int32, (k_pad, block), 0)
+        per_tuple = dest_fn(rows, msk, enc_ref, dest_ref, kiota)
+        _write_ranks(
+            dest_ref, rank_ref, per_tuple, counts_ref[...], kiota, w_out
+        )
+        counts_ref[...] += per_tuple.sum(axis=1, keepdims=True)
 
 
-def _fused_dma_kernel(
-    rows_hbm, *out_refs, routes, sketch_cols, seeds, width, k_pad, block, nsteps
+def _ingest_call(
+    rows, enc_flat, dest_fn, w_out, k_pad, sketch_cols, seeds, width, block,
+    interpret, double_buffer,
 ):
-    """Double-buffered variant: rows stay in HBM; two VMEM slots are filled
-    by async DMA so the copy of block i+1 overlaps the compute on block i
-    (DESIGN.md §7)."""
-    dest_ref, rank_ref, counts_ref, cms_ref = _unpack_refs(
-        out_refs, with_route=bool(routes), with_sketch=bool(sketch_cols)
+    """Shared ``pallas_call`` plumbing of the static and dense variants.
+
+    The kernel works on the transposed batch (tuples along lanes) and its
+    ``dest``/``rank`` come out as ``[w_out, N_pad]``; this returns them as
+    padded ``[N_pad, w_out]``, with ``counts [k_pad]`` and
+    ``cms [n_cols·depth, width]``.  Route outputs are None when ``w_out``
+    is 0, ``cms`` is None without ``sketch_cols``."""
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
+    if w_out > MAX_ROUTE_WIDTH:
+        raise ValueError(
+            f"route width {w_out} exceeds the exact rank-window bound "
+            f"{MAX_ROUTE_WIDTH}"
+        )
+    n, arity = rows.shape
+    with_route, with_sketch = w_out > 0, bool(sketch_cols)
+    block = _block_rows(block, k_pad if with_route else 0)
+    n_pad = max(_round_up(n, block), block)
+    nsteps = n_pad // block
+    # transposed batch, the validity mask as row ``arity``; rows padded to
+    # a sublane tile so each block is one aligned DMA
+    n_rows_t = _round_up(arity + 1, 8)
+    rows_t = jnp.concatenate(
+        [rows.astype(jnp.int32).T, jnp.ones((1, n), jnp.int32)], axis=0
     )
-    if counts_ref is not None:
-        counts_ref[...] = jnp.zeros_like(counts_ref)
-    if cms_ref is not None:
-        cms_ref[...] = jnp.zeros_like(cms_ref)
+    rows_t = jnp.pad(rows_t, ((0, n_rows_t - arity - 1), (0, n_pad - n)))
 
-    def body(scratch, sem):
-        def get_dma(slot, i):
-            return pltpu.make_async_copy(
-                rows_hbm.at[pl.ds(i * block, block), :],
-                scratch.at[slot],
-                sem.at[slot],
-            )
+    out_shapes, out_specs = [], []
+    if with_route:
+        out_shapes += [
+            jax.ShapeDtypeStruct((w_out, n_pad), jnp.int32),  # dest
+            jax.ShapeDtypeStruct((w_out, n_pad), jnp.int32),  # rank
+            jax.ShapeDtypeStruct((k_pad, 1), jnp.int32),  # counts
+        ]
+        out_specs += [
+            pl.BlockSpec((w_out, block), lambda i: (0, i)),
+            pl.BlockSpec((w_out, block), lambda i: (0, i)),
+            pl.BlockSpec((k_pad, 1), lambda i: (0, 0)),
+        ]
+    if with_sketch:
+        n_rows = len(sketch_cols) * len(seeds)
+        out_shapes.append(jax.ShapeDtypeStruct((n_rows, width), jnp.int32))
+        out_specs.append(pl.BlockSpec((n_rows, width), lambda i: (0, 0)))
 
-        get_dma(0, 0).start()
+    if double_buffer:
+        in_specs = [pl.BlockSpec(memory_space=pltpu.HBM)]
+        scratch = [
+            pltpu.VMEM((2, n_rows_t, block), jnp.int32),
+            pltpu.SemaphoreType.DMA((2,)),
+        ]
+    else:
+        in_specs = [pl.BlockSpec((n_rows_t, block), lambda i: (0, i))]
+        scratch = []
+    operands = [rows_t]
+    if enc_flat is not None:
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        operands.append(enc_flat)
 
-        def step(i, _):
-            cur, nxt = i % 2, (i + 1) % 2
+    outs = pl.pallas_call(
+        functools.partial(
+            _ingest_kernel, dest_fn=dest_fn, has_enc=enc_flat is not None,
+            with_route=with_route, with_sketch=with_sketch,
+            sketch_cols=tuple(sketch_cols), seeds=tuple(seeds), width=width,
+            k_pad=k_pad, w_out=w_out, arity=arity, block=block, nsteps=nsteps,
+            double_buffer=double_buffer,
+        ),
+        grid=(nsteps,),
+        in_specs=in_specs,
+        out_specs=tuple(out_specs),
+        out_shape=tuple(out_shapes),
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
+        interpret=interpret,
+    )(*operands)
 
-            @pl.when(i + 1 < nsteps)
-            def _prefetch():
-                get_dma(nxt, i + 1).start()
-
-            get_dma(cur, i).wait()
-            blk = scratch[cur]
-            rows, msk = blk[:, :-1], blk[:, -1] != 0
-            if cms_ref is not None:
-                cms_ref[...] += _cms_block(rows, msk, sketch_cols, seeds, width)
-            if dest_ref is not None:
-                dest = _dest_block(rows, msk, routes)
-                rank, delta = _rank_counts_block(dest, counts_ref[...], k_pad)
-                dest_ref[pl.ds(i * block, block), :] = dest
-                rank_ref[pl.ds(i * block, block), :] = rank
-                counts_ref[...] += delta
-            return _
-
-        jax.lax.fori_loop(0, nsteps, step, None)
-
-    pl.run_scoped(
-        body,
-        scratch=pltpu.VMEM((2, block, rows_hbm.shape[1]), jnp.int32),
-        sem=pltpu.SemaphoreType.DMA((2,)),
-    )
-
-
-def _round_up(n: int, m: int) -> int:
-    return ((n + m - 1) // m) * m
+    outs = list(outs)
+    dest = rank = counts = cms = None
+    if with_route:
+        dest, rank, counts = outs[0].T, outs[1].T, outs[2].reshape(-1)
+    if with_sketch:
+        cms = outs[-1]
+    return dest, rank, counts, cms
 
 
 def fused_ingest_pallas(
@@ -242,80 +340,17 @@ def fused_ingest_pallas(
         raise ValueError("fused ingest needs routes and/or sketch_cols")
     if sketch_cols and not seeds:
         raise ValueError("sketching requires the Count-Min row seeds")
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    n, arity = rows.shape
-    w = route_width(routes)
-    depth = len(seeds)
-    n_cols = len(sketch_cols)
-
-    # block size: keep the dense order-comparison window (block*W)^2 at
-    # ~VMEM scale regardless of the plan's replication width
-    if w:
-        while block > 8 and block * w > 1024:
-            block //= 2
-    n_pad = max(_round_up(n, block), block)
+    n = rows.shape[0]
     k_pad = max(_round_up(num_reducers, 128), 128)
-
-    mask = jnp.ones((n,), jnp.int32)
-    rows_aug = jnp.concatenate([rows.astype(jnp.int32), mask[:, None]], axis=1)
-    if n_pad != n:
-        rows_aug = jnp.concatenate(
-            [rows_aug, jnp.zeros((n_pad - n, arity + 1), jnp.int32)]
-        )
-    nsteps = n_pad // block
-
-    out_shapes, out_specs = [], []
-    if routes:
-        out_shapes += [
-            jax.ShapeDtypeStruct((n_pad, w), jnp.int32),  # dest
-            jax.ShapeDtypeStruct((n_pad, w), jnp.int32),  # rank
-            jax.ShapeDtypeStruct((k_pad,), jnp.int32),  # counts
-        ]
-        out_specs += [
-            pl.BlockSpec((block, w), lambda i: (i, 0)),
-            pl.BlockSpec((block, w), lambda i: (i, 0)),
-            pl.BlockSpec((k_pad,), lambda i: (0,)),
-        ]
-    if sketch_cols:
-        out_shapes.append(jax.ShapeDtypeStruct((n_cols * depth, width), jnp.int32))
-        out_specs.append(pl.BlockSpec((n_cols * depth, width), lambda i: (0, 0)))
-
-    common = dict(
-        routes=routes, sketch_cols=sketch_cols, seeds=tuple(seeds),
-        width=width, k_pad=k_pad,
+    dest, rank, counts, cms = _ingest_call(
+        rows, None, functools.partial(_dest_static, routes=routes),
+        route_width(routes), k_pad, sketch_cols, seeds, width, block,
+        interpret, double_buffer,
     )
-    if double_buffer:
-        outs = pl.pallas_call(
-            functools.partial(
-                _fused_dma_kernel, block=block, nsteps=nsteps, **common
-            ),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-            out_specs=tuple(
-                pl.BlockSpec(memory_space=pltpu.VMEM) for _ in out_shapes
-            ),
-            out_shape=tuple(out_shapes),
-            interpret=interpret,
-        )(rows_aug)
-    else:
-        outs = pl.pallas_call(
-            functools.partial(_fused_grid_kernel, **common),
-            grid=(nsteps,),
-            in_specs=[pl.BlockSpec((block, arity + 1), lambda i: (i, 0))],
-            out_specs=tuple(out_specs),
-            out_shape=tuple(out_shapes),
-            interpret=interpret,
-        )(rows_aug)
-
-    outs = list(outs)
-    dest = rank = counts = cms = None
     if routes:
-        dest = outs[0][:n]
-        rank = outs[1][:n]
-        counts = outs[2][:num_reducers]
-        outs = outs[3:]
+        dest, rank, counts = dest[:n], rank[:n], counts[:num_reducers]
     if sketch_cols:
-        cms = outs[-1].reshape(n_cols, depth, width)
+        cms = cms.reshape(len(sketch_cols), len(seeds), width)
     return dest, rank, counts, cms
 
 
@@ -327,13 +362,13 @@ def fused_ingest_pallas(
 # the batch-0/5 spikes in BENCH_stream.json.  The dense variant passes the
 # SAME routing recipe as data: per padded output column, a base offset plus
 # padded per-attr (seed, dim, stride) hash terms, pin equalities, and
-# exclude lists, all as int32 arrays.  Only the *padded shapes* are static
-# — (W_pad, H, P, V) derived from the relation arity and the config's HH
-# cap — so replans that stay within the same power-of-two replication
+# exclude lists, all as int32 scalars in SMEM.  Only the *padded shapes* are
+# static — (W_pad, H, P, V) derived from the relation arity and the config's
+# HH cap — so replans that stay within the same power-of-two replication
 # bucket reuse the compiled executable and pay microseconds, not seconds.
-# Column selection uses one-hot iota comparisons (no data-dependent gather,
-# Pallas-safe) and the arithmetic is term-for-term identical to
-# ``_dest_block``, so destinations stay bit-identical to ``map_phase``.
+# Column selection multiplies each attribute column by a 0/1 scalar (no
+# data-dependent gather) and the arithmetic is term-for-term identical to
+# ``_dest_rows``, so destinations stay bit-identical to ``map_phase``.
 
 def dense_route_encoding(
     routes: RouteTable,
@@ -344,7 +379,7 @@ def dense_route_encoding(
     """Encode a static route table as dense int32 arrays (dynamic operands).
 
     Shapes: per padded flat column ``w < w_pad`` (real columns first, in
-    ``_dest_block``'s residual-major/replica-minor order):
+    ``_dest_rows``'s residual-major/replica-minor order):
 
       * ``col_base [Wp]``   — residual offset + replica offset (0 padded)
       * ``col_valid [Wp]``  — 1 for real columns
@@ -419,116 +454,52 @@ _ENC_KEYS = (
 )
 
 
-def _dest_block_dense(rows, msk, enc):
-    """[B, Wp] destination ids from the dense encoding (−1 = not emitted).
+def _dest_dense(cols, msk, enc_ref, dest_ref, kiota, *, layout):
+    """``_dest_static`` with the route terms read as scalars from the flat
+    SMEM encoding (``layout[key] = (offset, shape)`` of ``enc[key]`` in it),
+    one loop iteration per padded column."""
+    wp, h = layout["h_col"][1]
+    _, p, v = layout["e_val"][1]
 
-    Same math as ``_dest_block``, vectorized over padded columns; column
-    selection is a one-hot multiply against an arity iota (no gather)."""
-    b, arity = rows.shape
-    wp, h = enc["h_col"].shape
-    v = enc["e_val"].shape[2]
+    def at(key, *idx):  # scalar enc[key][idx] (row-major)
+        off, shape = layout[key]
+        flat = 0
+        for i, dim in zip(idx, shape):
+            flat = flat * dim + i
+        return enc_ref[off + flat]
 
-    def select(cols):  # cols [Wp, T] -> values [B, Wp, T]
-        t = cols.shape[1]
-        oh = (
-            cols[:, :, None]
-            == jax.lax.broadcasted_iota(jnp.int32, (wp, t, arity), 2)
-        ).astype(jnp.int32)
-        return (rows[:, None, None, :] * oh[None]).sum(-1)
+    def select(c):  # dynamic attribute id -> its [1, B] row
+        out = cols[0] * (c == 0).astype(jnp.int32)
+        for a in range(1, len(cols)):
+            out = out + cols[a] * (c == a).astype(jnp.int32)
+        return out
 
-    hv = select(enc["h_col"])  # [B, Wp, H]
-    bucket = (
-        _mix32(hv, enc["h_seed"][None])
-        % enc["h_dim"][None].astype(jnp.uint32)
-    ).astype(jnp.int32)
-    base = enc["col_base"][None, :] + (bucket * enc["h_stride"][None]).sum(-1)
-
-    pv = select(enc["p_col"])  # [B, Wp, P]
-    pin_ok = ((pv == enc["p_val"][None]) | (enc["p_on"][None] == 0)).all(-1)
-
-    ev = select(enc["e_col"])  # [B, Wp, P]
-    bad = (
-        (ev[:, :, :, None] == enc["e_val"][None])
-        & (enc["e_on"][None] != 0)
-    ).any((-1, -2))
-
-    ok = msk[:, None] & (enc["col_valid"][None] != 0) & pin_ok & ~bad
-    return jnp.where(ok, base, jnp.int32(-1))
-
-
-def _fused_grid_kernel_dense(
-    rows_ref, *refs, with_sketch, sketch_cols, seeds, width, k_pad
-):
-    enc = {k: r[...] for k, r in zip(_ENC_KEYS, refs[: len(_ENC_KEYS)])}
-    dest_ref, rank_ref, counts_ref, cms_ref = _unpack_refs(
-        refs[len(_ENC_KEYS):], with_route=True, with_sketch=with_sketch
-    )
-    step = pl.program_id(0)
-
-    @pl.when(step == 0)
-    def _init():
-        counts_ref[...] = jnp.zeros_like(counts_ref)
-        if cms_ref is not None:
-            cms_ref[...] = jnp.zeros_like(cms_ref)
-
-    blk = rows_ref[...]
-    rows, msk = blk[:, :-1], blk[:, -1] != 0
-    if cms_ref is not None:
-        cms_ref[...] += _cms_block(rows, msk, sketch_cols, seeds, width)
-    dest = _dest_block_dense(rows, msk, enc)
-    rank, delta = _rank_counts_block(dest, counts_ref[...], k_pad)
-    dest_ref[...] = dest
-    rank_ref[...] = rank
-    counts_ref[...] += delta
-
-
-def _fused_dma_kernel_dense(
-    rows_hbm, *refs, with_sketch, sketch_cols, seeds, width, k_pad, block,
-    nsteps,
-):
-    enc = {k: r[...] for k, r in zip(_ENC_KEYS, refs[: len(_ENC_KEYS)])}
-    dest_ref, rank_ref, counts_ref, cms_ref = _unpack_refs(
-        refs[len(_ENC_KEYS):], with_route=True, with_sketch=with_sketch
-    )
-    counts_ref[...] = jnp.zeros_like(counts_ref)
-    if cms_ref is not None:
-        cms_ref[...] = jnp.zeros_like(cms_ref)
-
-    def body(scratch, sem):
-        def get_dma(slot, i):
-            return pltpu.make_async_copy(
-                rows_hbm.at[pl.ds(i * block, block), :],
-                scratch.at[slot],
-                sem.at[slot],
+    def column(w, per_tuple):
+        base = jnp.full(msk.shape, at("col_base", w), jnp.int32)
+        for j in range(h):
+            bucket = (
+                _mix32(select(at("h_col", w, j)), at("h_seed", w, j))
+                % at("h_dim", w, j).astype(jnp.uint32)
+            ).astype(jnp.int32)
+            base = base + bucket * at("h_stride", w, j)
+        # count failed pins and matched excludes; emit only when zero
+        bad = jnp.full(msk.shape, 1 - at("col_valid", w), jnp.int32)
+        for j in range(p):
+            pv = select(at("p_col", w, j))
+            bad = bad + (pv != at("p_val", w, j)).astype(jnp.int32) * at(
+                "p_on", w, j
             )
+            ev = select(at("e_col", w, j))
+            for k in range(v):
+                bad = bad + (ev == at("e_val", w, j, k)).astype(
+                    jnp.int32
+                ) * at("e_on", w, j, k)
+        d = jnp.where(msk & (bad == 0), base, jnp.int32(-1))
+        dest_ref[pl.ds(w, 1), :] = d
+        return per_tuple + (d == kiota).astype(jnp.int32)
 
-        get_dma(0, 0).start()
-
-        def step(i, _):
-            cur, nxt = i % 2, (i + 1) % 2
-
-            @pl.when(i + 1 < nsteps)
-            def _prefetch():
-                get_dma(nxt, i + 1).start()
-
-            get_dma(cur, i).wait()
-            blk = scratch[cur]
-            rows, msk = blk[:, :-1], blk[:, -1] != 0
-            if cms_ref is not None:
-                cms_ref[...] += _cms_block(rows, msk, sketch_cols, seeds, width)
-            dest = _dest_block_dense(rows, msk, enc)
-            rank, delta = _rank_counts_block(dest, counts_ref[...], k_pad)
-            dest_ref[pl.ds(i * block, block), :] = dest
-            rank_ref[pl.ds(i * block, block), :] = rank
-            counts_ref[...] += delta
-            return _
-
-        jax.lax.fori_loop(0, nsteps, step, None)
-
-    pl.run_scoped(
-        body,
-        scratch=pltpu.VMEM((2, block, rows_hbm.shape[1]), jnp.int32),
-        sem=pltpu.SemaphoreType.DMA((2,)),
+    return jax.lax.fori_loop(
+        0, wp, column, jnp.zeros(kiota.shape, jnp.int32)
     )
 
 
@@ -556,90 +527,50 @@ def fused_ingest_dense_pallas(
     silently corrupts counts/ranks (the engine rounds total_reducers up to
     a 128 multiple in ``_dense_routes``).
     """
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    n, arity = rows.shape
-    wp = enc["col_base"].shape[0]
-    depth = len(seeds)
-    n_cols = len(sketch_cols)
-
-    block = int(block)
-    while block > 8 and block * wp > 1024:
-        block //= 2
-    n_pad = max(_round_up(n, block), block)
-
-    mask = jnp.ones((n,), jnp.int32)
-    rows_aug = jnp.concatenate([rows.astype(jnp.int32), mask[:, None]], axis=1)
-    if n_pad != n:
-        rows_aug = jnp.concatenate(
-            [rows_aug, jnp.zeros((n_pad - n, arity + 1), jnp.int32)]
-        )
-    nsteps = n_pad // block
-    enc_arrays = [jnp.asarray(enc[k], jnp.int32) for k in _ENC_KEYS]
-
-    out_shapes = [
-        jax.ShapeDtypeStruct((n_pad, wp), jnp.int32),  # dest
-        jax.ShapeDtypeStruct((n_pad, wp), jnp.int32),  # rank
-        jax.ShapeDtypeStruct((k_pad,), jnp.int32),  # counts
-    ]
-    out_specs = [
-        pl.BlockSpec((block, wp), lambda i: (i, 0)),
-        pl.BlockSpec((block, wp), lambda i: (i, 0)),
-        pl.BlockSpec((k_pad,), lambda i: (0,)),
-    ]
-    if sketch_cols:
-        out_shapes.append(
-            jax.ShapeDtypeStruct((n_cols * depth, width), jnp.int32)
-        )
-        out_specs.append(pl.BlockSpec((n_cols * depth, width), lambda i: (0, 0)))
-
-    common = dict(
-        with_sketch=bool(sketch_cols), sketch_cols=sketch_cols,
-        seeds=tuple(seeds), width=width, k_pad=k_pad,
+    layout, off, flat = {}, 0, []
+    for key in _ENC_KEYS:
+        arr = jnp.asarray(enc[key], jnp.int32)
+        layout[key] = (off, arr.shape)
+        off += arr.size
+        flat.append(arr.reshape(-1))
+    wp = layout["col_base"][1][0]
+    dest, rank, counts, cms = _ingest_call(
+        rows, jnp.concatenate(flat),
+        functools.partial(_dest_dense, layout=layout),
+        wp, k_pad, sketch_cols, seeds, width, block, interpret, double_buffer,
     )
-    if double_buffer:
-        outs = pl.pallas_call(
-            functools.partial(
-                _fused_dma_kernel_dense, block=block, nsteps=nsteps, **common
-            ),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)]
-            + [pl.BlockSpec(memory_space=pltpu.VMEM) for _ in enc_arrays],
-            out_specs=tuple(
-                pl.BlockSpec(memory_space=pltpu.VMEM) for _ in out_shapes
-            ),
-            out_shape=tuple(out_shapes),
-            interpret=interpret,
-        )(rows_aug, *enc_arrays)
-    else:
-        outs = pl.pallas_call(
-            functools.partial(_fused_grid_kernel_dense, **common),
-            grid=(nsteps,),
-            in_specs=[pl.BlockSpec((block, arity + 1), lambda i: (i, 0))]
-            + [
-                pl.BlockSpec(a.shape, _zero_index_map(a.ndim))
-                for a in enc_arrays
-            ],
-            out_specs=tuple(out_specs),
-            out_shape=tuple(out_shapes),
-            interpret=interpret,
-        )(rows_aug, *enc_arrays)
-
-    outs = list(outs)
-    cms = None
     if sketch_cols:
-        cms = outs[-1].reshape(n_cols, depth, width)
-    return outs[0], outs[1], outs[2], cms
-
-
-def _zero_index_map(ndim: int):
-    return lambda i, _nd=ndim: (0,) * _nd
+        cms = cms.reshape(len(sketch_cols), len(seeds), width)
+    return dest, rank, counts, cms
 
 
 # ---- roofline / overlap model (DESIGN.md §7) -------------------------------
-# Per-chip numbers for a TPU v5e-class part; the model is about orders of
-# magnitude, not decimal places.
-HBM_BYTES_PER_S = 819e9  # ~819 GB/s HBM bandwidth
-VPU_INT_OPS_PER_S = 3.0e12  # 8x128 VPU lanes, ~1 op/lane/cycle @ ~940MHz x ~4
+# Per-chip peaks, keyed by ``jax.devices()[0].device_kind``.  A kind that is
+# not listed raises: a model fed another chip's peaks is wrong, not rough.
+CHIP_PEAKS = {
+    "TPU v5 lite": {
+        # Google Cloud TPU documentation, "TPU v5e": 819 GB/s HBM2
+        "hbm_bytes_per_s": 819e9,
+        # same page: 197 TFLOP/s bf16 per chip
+        "mxu_flops_per_s": 197e12,
+        # derived, one 32-bit op per VPU lane per cycle: the same page's
+        # 197 TFLOP/s over its 4 MXUs of 128x128 MACs gives a 1.5 GHz
+        # clock; times the 8x128-lane vector unit
+        "vpu_ops_per_s": 197e12 / (4 * 128 * 128 * 2) * 8 * 128,
+    },
+}
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """The peak table entry of one device kind; unknown kinds raise."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak figures for device kind {device_kind!r}; known: "
+            f"{sorted(CHIP_PEAKS)}"
+        ) from None
+
 
 def overlap_profile(
     n_rows: int,
@@ -649,41 +580,46 @@ def overlap_profile(
     n_sketch_cols: int,
     depth: int,
     width: int,
+    *,
+    device_kind: str,
     block: int = 256,
 ) -> dict:
-    """Model the fused pass against the hardware roofline.
+    """Model the fused pass against the roofline of ``device_kind``.
 
-    Returns modeled HBM traffic, VPU work, the serial vs double-buffered
-    time, and which side of the roofline binds.  ``bench_stream`` writes
-    this next to the measured wall times so the gap between "what the
-    kernel does" and "what the host pays" stays visible.
+    Returns modeled HBM traffic, VPU and MXU work, the serial vs
+    double-buffered time, and which side of the roofline binds.
+    ``bench_stream`` writes this next to the measured wall times so the gap
+    between "what the kernel does" and "what the host pays" stays visible.
     """
-    if route_w:
-        while block > 8 and block * route_w > 1024:
-            block //= 2
+    peaks = chip_peaks(device_kind)
+    k_pad = max(_round_up(num_reducers, 128), 128)
+    block = _block_rows(block, k_pad if route_w else 0)
     bytes_in = n_rows * (arity + 1) * 4
     bytes_out = (2 * n_rows * route_w + num_reducers + n_sketch_cols * depth * width) * 4
-    dma_s = (bytes_in + bytes_out) / HBM_BYTES_PER_S
+    dma_s = (bytes_in + bytes_out) / peaks["hbm_bytes_per_s"]
 
-    e = block * route_w  # flat emissions per block
-    nsteps = max(1, -(-n_rows // block)) if block else 1
-    k_pad = max(_round_up(num_reducers, 128), 128)
-    ops_rank = nsteps * (3 * e * e + 3 * e * k_pad)  # order compare + one-hot
+    nsteps = max(1, -(-n_rows // block))
+    ops_rank = n_rows * route_w * k_pad * 5  # one-hot, select, sum, carry
     ops_dest = n_rows * route_w * 8  # mix32 + pin/exclude masks
     ops_cms = n_rows * n_sketch_cols * depth * (width * 2 + 8)
     vpu_ops = ops_rank + ops_dest + ops_cms
-    compute_s = vpu_ops / VPU_INT_OPS_PER_S
+    mxu_flops = nsteps * 2 * block * block * k_pad if route_w else 0
+    compute_s = (
+        vpu_ops / peaks["vpu_ops_per_s"] + mxu_flops / peaks["mxu_flops_per_s"]
+    )
 
     serial_s = dma_s + compute_s
     overlapped_s = max(dma_s, compute_s)
     return {
+        "device_kind": device_kind,
         "bytes_in": bytes_in,
         "bytes_out": bytes_out,
         "vpu_ops": vpu_ops,
+        "mxu_flops": mxu_flops,
         "dma_us": dma_s * 1e6,
         "compute_us": compute_s * 1e6,
         "serial_us": serial_s * 1e6,
         "overlapped_us": overlapped_s * 1e6,
         "overlap_speedup": serial_s / overlapped_s if overlapped_s else 1.0,
-        "bound": "compute" if compute_s >= dma_s else "memory",
+        "bound": "compute" if compute_s >= dma_s else "dma",
     }

@@ -9,40 +9,18 @@ scatter-add serializes on TPU), computed once per hash row with the mix32
 universal family of ``repro.mapreduce.hashing`` so host and device buckets
 agree bit-for-bit.
 
-Grid: one step per value block; the single [depth, width] output block is
-revisited every step and accumulated in VMEM.  Invalid slots (padding) are
-masked out via an explicit mask input — any int32 value is a legal key, so
-no in-band sentinel exists.
+The increment is the sketch-only mode of the fused ingest kernel
+(``kernels.ingest_fused``) over a one-column relation: the same 2-D tuple
+blocks (multiples of 128 rows, short inputs padded, never a shrunken
+block) and the same resident [depth, width] accumulator.  Padding rows are
+masked out by the kernel's validity column — any int32 value is a legal
+key, so no in-band sentinel exists.
 """
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-# plain jnp ops, legal inside a Pallas kernel body — ONE definition of the
-# hash family keeps host estimates and device increments in sync
-from repro.mapreduce.hashing import mix32_jnp as _mix32
-
-
-def _cms_update_kernel(
-    vals_ref, mask_ref, out_ref, *, seeds: tuple[int, ...], width: int
-):
-    step = pl.program_id(0)
-
-    @pl.when(step == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    vals = vals_ref[...]  # [block]
-    mask = mask_ref[...] != 0  # [block]
-    bins = jax.lax.broadcasted_iota(jnp.int32, (vals.shape[0], width), 1)
-    for row, seed in enumerate(seeds):
-        bucket = (_mix32(vals, seed) % jnp.uint32(width)).astype(jnp.int32)
-        onehot = (bucket[:, None] == bins) & mask[:, None]
-        out_ref[row, :] += onehot.astype(jnp.int32).sum(axis=0)
+from .ingest_fused import fused_ingest_pallas
 
 
 def cms_update_pallas(
@@ -57,27 +35,15 @@ def cms_update_pallas(
     ``seeds`` selects the mix32 hash row family (one seed per sketch row);
     the caller's sketch must use the same seeds for buckets to line up.
     """
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    depth = len(seeds)
-    n = values.shape[0]
-    if n == 0:
-        return jnp.zeros((depth, width), jnp.int32)
-    block = min(block, max(n, 1))
-    pad = (-n) % block
-    mask = jnp.ones(n, dtype=jnp.int32)
-    if pad:
-        values = jnp.concatenate([values, jnp.zeros(pad, values.dtype)])
-        mask = jnp.concatenate([mask, jnp.zeros(pad, jnp.int32)])
-    grid = (values.shape[0] // block,)
-    return pl.pallas_call(
-        functools.partial(_cms_update_kernel, seeds=tuple(seeds), width=width),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((depth, width), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((depth, width), jnp.int32),
+    if values.shape[0] == 0:
+        return jnp.zeros((len(seeds), width), jnp.int32)
+    _, _, _, cms = fused_ingest_pallas(
+        values.astype(jnp.int32)[:, None],
+        sketch_cols=(0,),
+        seeds=tuple(seeds),
+        width=width,
+        block=block,
         interpret=interpret,
-    )(values.astype(jnp.int32), mask)
+        double_buffer=False,
+    )
+    return cms[0]

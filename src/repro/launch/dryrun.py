@@ -226,8 +226,6 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str, extra_slots: 
             t2 = time.time()
             mem = compiled.memory_analysis()
             cost = compiled.cost_analysis()
-            if isinstance(cost, (list, tuple)):  # older jax wraps in a list
-                cost = cost[0] if cost else None
             text = compiled.as_text()
         from repro.launch.hlo_analysis import analyze
 

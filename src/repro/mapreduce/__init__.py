@@ -9,7 +9,7 @@ from .local_join import (
     materialize_two_way,
 )
 from .naive import NaiveStats, naive_two_way
-from .oracle import oracle_join
+from .oracle import keyed_join_fingerprint, oracle_join
 from .shuffle import run_distributed
 
 __all__ = [
@@ -20,6 +20,7 @@ __all__ = [
     "build_pipeline",
     "build_route_specs",
     "group_by_reducer",
+    "keyed_join_fingerprint",
     "local_join_count_checksum",
     "map_phase",
     "materialize_two_way",

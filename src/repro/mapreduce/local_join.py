@@ -102,16 +102,18 @@ def _match_matrix(
     return m
 
 
-def local_join_count_checksum(
+def local_join_per_reducer(
     spec: LocalJoinSpec,
     bins: dict[str, jnp.ndarray],  # name -> [K, cap, arity]
     valids: dict[str, jnp.ndarray],  # name -> [K, cap]
     weight_seed: int = 0x5EED,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Per-reducer-batched multiway join. Returns (count, checksum) scalars.
+    """Per-reducer-batched multiway join: (count [K], checksum [K]) int32.
 
     checksum = sum over joined tuples of the product of per-relation tuple
-    weights (mod 2^32, reported as uint32) — orderless, matches the oracle.
+    weights (mod 2^32 as int32 wraparound) — orderless, matches the oracle.
+    A reducer's count is exact while it stays below 2^31; sums across
+    reducers are the caller's, in whatever width the total needs.
     """
     n = len(spec.rel_names)
     letters = _EINSUM_LETTERS[:n]
@@ -153,6 +155,17 @@ def local_join_count_checksum(
         *[o.astype(jnp.int32) for o in ones],
         *[w.astype(jnp.int32) for w in w_ops],
     )
+    return count, checksum
+
+
+def local_join_count_checksum(
+    spec: LocalJoinSpec,
+    bins: dict[str, jnp.ndarray],  # name -> [K, cap, arity]
+    valids: dict[str, jnp.ndarray],  # name -> [K, cap]
+    weight_seed: int = 0x5EED,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``local_join_per_reducer`` summed: (count int32, checksum uint32)."""
+    count, checksum = local_join_per_reducer(spec, bins, valids, weight_seed)
     return jnp.sum(count), jnp.sum(checksum).astype(jnp.uint32)
 
 

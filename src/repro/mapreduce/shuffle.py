@@ -15,32 +15,15 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # newer jax exports shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # older jax keeps it in experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-compat shard_map: the replication-check kwarg was renamed
-    check_rep -> check_vma across jax releases."""
-    try:
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    except TypeError:
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-        )
 
 from repro.core.planner import SharesSkewPlan
 from repro.core.schema import JoinQuery
 
 from .executor import JoinResult, _bin_cap, predicted_comm
 from .keys import map_phase
-from .local_join import LocalJoinSpec, group_by_reducer, local_join_count_checksum
+from .local_join import LocalJoinSpec, group_by_reducer, local_join_per_reducer
 
 
 def _pad_shard(arr: np.ndarray, d: int) -> tuple[np.ndarray, np.ndarray]:
@@ -54,30 +37,26 @@ def _pad_shard(arr: np.ndarray, d: int) -> tuple[np.ndarray, np.ndarray]:
     return out, mask
 
 
-def run_distributed(
+def distributed_program(
     query: JoinQuery,
-    data: dict[str, np.ndarray],
     plan: SharesSkewPlan,
-    mesh: Mesh | None = None,
+    mesh: Mesh,
     axis_name: str = "shuffle",
     cap_factor: float = 3.0,
     route_cap_factor: float = 3.0,
-) -> JoinResult:
-    if not plan.residuals:  # some relation is empty -> join is empty
-        return JoinResult(
-            count=0,
-            checksum=0,
-            comm_tuples={r.name: 0 for r in query.relations},
-            reducer_loads=np.zeros(0, dtype=np.int32),
-            overflow=0,
-        )
-    if mesh is None:
-        devs = np.array(jax.devices())
-        mesh = Mesh(devs, (axis_name,))
+):
+    """The jitted map → all_to_all → reduce program of ``run_distributed``.
+
+    It takes ``(rows, masks)``, one tuple each of the relations' rows
+    ``[n_pad, arity]`` int32 and validity ``[n_pad]`` bool, sharded over
+    ``axis_name``, and returns ``(counts [k_pad], checksum, comm [n_rel],
+    loads [k_pad], overflow)`` — counts per reducer, so the caller sums a
+    total past 2^31 exactly.  Static over the plan and the mesh only, so
+    it can be lowered for a described topology from shapes alone.
+    """
     d = mesh.shape[axis_name]
     k = plan.total_reducers
     g = int(math.ceil(k / d))  # reducers per device
-    k_pad = g * d
     cap = _bin_cap(plan, cap_factor)
     spec = LocalJoinSpec.from_query(query)
 
@@ -86,12 +65,6 @@ def run_distributed(
         name: max(32, int(math.ceil(pred[name] / (d * d) * route_cap_factor)) + 16)
         for name in pred
     }
-
-    rel_order = [r.name for r in query.relations]
-    padded, masks = {}, {}
-    for name in rel_order:
-        arr = np.asarray(data[name], dtype=np.int32)
-        padded[name], masks[name] = _pad_shard(arr, d)
 
     def stage(rows_list, mask_list):
         my_dev = jax.lax.axis_index(axis_name)
@@ -126,28 +99,62 @@ def run_distributed(
             bins[rel.name], valids[rel.name] = b, v
             loads_local = loads_local + loads
             overflow = overflow + ov1 + ov2
-        count, checksum = local_join_count_checksum(spec, bins, valids)
-        count = jax.lax.psum(count, axis_name)
-        checksum = jax.lax.psum(checksum.astype(jnp.int32), axis_name)
+        counts, checksum = local_join_per_reducer(spec, bins, valids)
+        checksum = jax.lax.psum(jnp.sum(checksum), axis_name)
         comm = [jax.lax.psum(c, axis_name) for c in comm]
         overflow = jax.lax.psum(overflow, axis_name)
-        return count, checksum, jnp.stack(comm), loads_local, overflow
+        return counts, checksum, jnp.stack(comm), loads_local, overflow
 
-    in_row_specs = [P(axis_name) for _ in rel_order]
-    in_mask_specs = [P(axis_name) for _ in rel_order]
+    n_rel = len(query.relations)
+    in_row_specs = [P(axis_name) for _ in range(n_rel)]
+    in_mask_specs = [P(axis_name) for _ in range(n_rel)]
     fn = shard_map(
         stage,
         mesh=mesh,
         in_specs=(tuple(in_row_specs), tuple(in_mask_specs)),
-        out_specs=(P(), P(), P(), P(axis_name), P()),
+        out_specs=(P(axis_name), P(), P(), P(axis_name), P()),
         check_vma=False,
+    )
+    return jax.jit(fn)
+
+
+def run_distributed(
+    query: JoinQuery,
+    data: dict[str, np.ndarray],
+    plan: SharesSkewPlan,
+    mesh: Mesh | None = None,
+    axis_name: str = "shuffle",
+    cap_factor: float = 3.0,
+    route_cap_factor: float = 3.0,
+) -> JoinResult:
+    if not plan.residuals:  # some relation is empty -> join is empty
+        return JoinResult(
+            count=0,
+            checksum=0,
+            comm_tuples={r.name: 0 for r in query.relations},
+            reducer_loads=np.zeros(0, dtype=np.int32),
+            overflow=0,
+        )
+    if mesh is None:
+        devs = np.array(jax.devices())
+        mesh = Mesh(devs, (axis_name,))
+    d = mesh.shape[axis_name]
+    k = plan.total_reducers
+    rel_order = [r.name for r in query.relations]
+    padded, masks = {}, {}
+    for name in rel_order:
+        arr = np.asarray(data[name], dtype=np.int32)
+        padded[name], masks[name] = _pad_shard(arr, d)
+
+    fn = distributed_program(
+        query, plan, mesh, axis_name, cap_factor, route_cap_factor
     )
     rows_in = tuple(jnp.asarray(padded[nm]) for nm in rel_order)
     masks_in = tuple(jnp.asarray(masks[nm]) for nm in rel_order)
-    count, checksum, comm, loads, overflow = jax.jit(fn)(rows_in, masks_in)
+    counts, checksum, comm, loads, overflow = fn(rows_in, masks_in)
     loads = np.asarray(loads)[:k]
     return JoinResult(
-        count=int(count),
+        count=int(np.asarray(counts).astype(np.int64).sum()),
         checksum=int(np.uint32(np.int64(checksum) & 0xFFFFFFFF)),
         comm_tuples={nm: int(c) for nm, c in zip(rel_order, np.asarray(comm))},
         reducer_loads=loads,

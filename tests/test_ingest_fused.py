@@ -172,12 +172,21 @@ def test_kernel_counts_are_destination_histogram():
 def test_overlap_profile_roofline_sanity():
     p = overlap_profile(
         n_rows=1500, arity=2, route_w=8, num_reducers=32,
-        n_sketch_cols=1, depth=4, width=2048,
+        n_sketch_cols=1, depth=4, width=2048, device_kind="TPU v5 lite",
     )
     assert p["bound"] in ("dma", "compute")
     assert p["overlapped_us"] <= p["serial_us"]
     assert 1.0 <= p["overlap_speedup"] <= 2.0
     assert p["bytes_in"] > 0 and p["vpu_ops"] > 0
+
+
+def test_overlap_profile_refuses_unknown_device_kind():
+    """The peaks table has no default: a kind it does not list raises."""
+    with pytest.raises(ValueError, match="no peak figures"):
+        overlap_profile(
+            n_rows=1500, arity=2, route_w=8, num_reducers=32,
+            n_sketch_cols=1, depth=4, width=2048, device_kind="cpu",
+        )
 
 
 # ------------------------------------------------- sorted delta index parity

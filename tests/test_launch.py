@@ -1,6 +1,7 @@
 """Launch layer: sharding rules, HLO analysis, dry-run cell, elastic restart."""
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -10,6 +11,8 @@ from jax.sharding import PartitionSpec as P
 
 from repro.launch.hlo_analysis import HloModule, analyze
 from repro.launch.sharding import batch_specs, cache_specs, opt_specs, param_specs
+
+_REPO = Path(__file__).resolve().parents[1]
 
 
 # ------------------------------------------------------------ sharding rules
@@ -111,6 +114,29 @@ def test_hlo_analysis_handles_tuple_shapes():
     assert "body" in mod.computations
 
 
+# ------------------------------------------------------------ compile cache
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_directory(monkeypatch, tmp_path, from_env):
+    """The cache goes where JAX_COMPILATION_CACHE_DIR says, else to the
+    fixed checkout path; small kernels are cached too."""
+    from repro.launch import compile_cache
+
+    updates = {}
+    monkeypatch.setattr(
+        compile_cache.jax.config, "update", lambda k, v: updates.update({k: v})
+    )
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert "jax_compilation_cache_dir" not in updates
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        path = compile_cache.enable_compile_cache()
+        assert path == str(_REPO / ".jax_cache")
+        assert updates["jax_compilation_cache_dir"] == path
+    assert updates["jax_persistent_cache_min_compile_time_secs"] == 0
+
+
 # ------------------------------------------------------------- dry-run cell
 def test_dryrun_single_cell_subprocess(tmp_path):
     """One real dry-run cell end to end (512 fake devices, 16x16 mesh)."""
@@ -122,8 +148,12 @@ def test_dryrun_single_cell_subprocess(tmp_path):
     proc = subprocess.run(
         [sys.executable, "-c", code],
         capture_output=True, text=True, timeout=900,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin"},
-        cwd="/root/repo",
+        env={
+            "PYTHONPATH": "src",
+            "PATH": "/usr/bin:/bin:/usr/local/bin",
+            "JAX_PLATFORMS": "cpu",
+        },
+        cwd=_REPO,
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     import json, os
@@ -150,6 +180,7 @@ from repro.train import (OptConfig, init_train_state, make_train_step,
                          save_checkpoint, load_checkpoint, restore_tree)
 from repro.train.elastic import plan_mesh_shape
 
+CKPT = os.environ["ELASTIC_CKPT"]
 cfg = get_config("olmo-1b").reduced()
 model = build_model(cfg)
 opt = OptConfig(lr=1e-3, warmup_steps=1, total_steps=10)
@@ -166,13 +197,13 @@ with mesh1:
     for _ in range(2):
         params, opt_state, m = step(params, opt_state, batch)
 loss_before = float(m["loss"])
-save_checkpoint("/tmp/elastic_ckpt", 2, {"params": params, "opt": opt_state})
+save_checkpoint(CKPT, 2, {"params": params, "opt": opt_state})
 
 # phase 2: "lose" half the devices -> mesh (2 data, 2 model); resharding restore
 plan = plan_mesh_shape(4, model_parallel=2, chips_per_pod=8)
 assert plan.model == 2 and plan.data * plan.model <= 4
 mesh2 = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
-_, flat = load_checkpoint("/tmp/elastic_ckpt")
+_, flat = load_checkpoint(CKPT)
 with mesh2:
     tree = restore_tree({"params": params, "opt": opt_state}, flat,
                         {"params": named(mesh2, p_spec),
@@ -184,12 +215,17 @@ print("ELASTIC_OK", loss_before, float(m2["loss"]))
 """
 
 
-def test_elastic_shrink_restart_subprocess():
+def test_elastic_shrink_restart_subprocess(tmp_path):
     proc = subprocess.run(
         [sys.executable, "-c", _ELASTIC_SNIPPET],
         capture_output=True, text=True, timeout=900,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin"},
-        cwd="/root/repo",
+        env={
+            "PYTHONPATH": "src",
+            "PATH": "/usr/bin:/bin:/usr/local/bin",
+            "JAX_PLATFORMS": "cpu",
+            "ELASTIC_CKPT": str(tmp_path / "ckpt"),
+        },
+        cwd=_REPO,
     )
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "ELASTIC_OK" in proc.stdout

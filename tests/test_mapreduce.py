@@ -1,6 +1,8 @@
 """Integration tests: the JAX MapReduce join engine vs the host oracle."""
+import os
 import subprocess
 import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -15,6 +17,7 @@ from repro.core import (
 )
 from repro.data import paper_2way, paper_3way, random_join_data
 from repro.mapreduce import (
+    keyed_join_fingerprint,
     naive_two_way,
     oracle_join,
     predicted_comm,
@@ -130,6 +133,41 @@ def test_naive_costs_more_than_shares_skew():
     assert res.total_comm < stats.comm_tuples
 
 
+# ----------------------------------------------------- per-key reference
+def _zipf_data(query, rng, a, n=300, domain=400):
+    """Each relation's join columns Zipf(a)-skewed, the others uniform."""
+    shared = {
+        x for r in query.relations for s in query.relations if r is not s
+        for x in set(r.attrs) & set(s.attrs)
+    }
+    return {
+        r.name: np.stack(
+            [
+                (rng.zipf(a, n) - 1) % domain if x in shared
+                else rng.integers(0, domain, n)
+                for x in r.attrs
+            ],
+            axis=1,
+        ).astype(np.int64)
+        for r in query.relations
+    }
+
+
+@pytest.mark.parametrize("query", [two_way(), three_way_paper()], ids=str)
+@pytest.mark.parametrize("a", [1.3, 1.7, 2.2])
+@pytest.mark.parametrize("seed", [0, 1])
+def test_keyed_join_fingerprint_matches_oracle(query, a, seed):
+    data = _zipf_data(query, np.random.default_rng(seed), a)
+    count, checksum, _, _ = oracle_join(query, data)
+    assert keyed_join_fingerprint(query, data) == (count, checksum)
+
+
+def test_keyed_join_fingerprint_refuses_cycles():
+    data = _zipf_data(triangle(), np.random.default_rng(0), 1.5)
+    with pytest.raises(ValueError, match="star"):
+        keyed_join_fingerprint(triangle(), data)
+
+
 # ------------------------------------------------------- distributed shuffle
 _DISTRIBUTED_SNIPPET = r"""
 import os
@@ -165,8 +203,12 @@ def test_distributed_shuffle_8_devices():
         capture_output=True,
         text=True,
         timeout=600,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin"},
-        cwd="/root/repo",
+        env={
+            "PYTHONPATH": "src",
+            "PATH": "/usr/bin:/bin:/usr/local/bin",
+            "JAX_PLATFORMS": "cpu",
+        },
+        cwd=Path(__file__).resolve().parents[1],
     )
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "DISTRIBUTED_OK" in proc.stdout

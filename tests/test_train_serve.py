@@ -150,7 +150,7 @@ def test_compressed_psum_error_feedback():
     # error feedback (residual telescopes)
     import jax
 
-    from repro.mapreduce.shuffle import shard_map
+    from jax import shard_map
 
     def step(g, r):
         return shard_map(
