@@ -230,7 +230,7 @@ def _ingest_kernel(
 
 def _ingest_call(
     rows, enc_flat, dest_fn, w_out, k_pad, sketch_cols, seeds, width, block,
-    interpret, double_buffer,
+    interpret, double_buffer, name=None,
 ):
     """Shared ``pallas_call`` plumbing of the static and dense variants.
 
@@ -238,7 +238,8 @@ def _ingest_call(
     ``dest``/``rank`` come out as ``[w_out, N_pad]``; this returns them as
     padded ``[N_pad, w_out]``, with ``counts [k_pad]`` and
     ``cms [n_cols·depth, width]``.  Route outputs are None when ``w_out``
-    is 0, ``cms`` is None without ``sketch_cols``."""
+    is 0, ``cms`` is None without ``sketch_cols``.  ``name`` labels the
+    kernel in a profile."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     if w_out > MAX_ROUTE_WIDTH:
@@ -307,6 +308,7 @@ def _ingest_call(
             dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
+        name=name,
     )(*operands)
 
     outs = list(outs)
@@ -538,6 +540,7 @@ def fused_ingest_dense_pallas(
         rows, jnp.concatenate(flat),
         functools.partial(_dest_dense, layout=layout),
         wp, k_pad, sketch_cols, seeds, width, block, interpret, double_buffer,
+        name="fused_ingest_dense",
     )
     if sketch_cols:
         cms = cms.reshape(len(sketch_cols), len(seeds), width)

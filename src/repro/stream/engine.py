@@ -177,8 +177,8 @@ class BatchReport:
     drift_trigger: str = ""
     drift_observed: float = 0.0
     drift_threshold: float = 0.0
-    # observability payload (metrics snapshot + skew snapshot) — excluded
-    # from equality: histogram sums carry wall time, and the baseline-vs-
+    # observability payload (the SkewScope snapshot; the metrics live in
+    # the engine's registry) — excluded from equality: the baseline-vs-
     # fused parity assertions compare everything else bit-for-bit
     obs: dict | None = dataclasses.field(default=None, compare=False, repr=False)
 
@@ -257,6 +257,7 @@ class StreamingJoinEngine:
             decay=config.decay,
             seed=config.sketch_seed,
             use_device_sketch=config.use_device_sketch,
+            obs=self.obs,
         )
         self.monitor = DriftMonitor(
             config.q,
@@ -455,12 +456,16 @@ class StreamingJoinEngine:
         return enc, w, k_pad
 
     def _fused_pass(
-        self, rel, rows: np.ndarray, with_route: bool, with_sketch: bool
+        self, rel, rows: np.ndarray, with_route: bool, with_sketch: bool,
+        moved: list[int] | None = None,
     ) -> tuple[_Routed | None, dict[str, np.ndarray] | None]:
         """One fused-kernel pass over ``rows`` (DESIGN.md §7).
 
         Returns (routed emissions under the CURRENT plan if ``with_route``,
-        per-attr Count-Min table increments if ``with_sketch``)."""
+        per-attr Count-Min table increments if ``with_sketch``).  ``moved``
+        (traced runs) accumulates ``[host-to-device, device-to-host]``
+        bytes: the rows and dense route encoding sent, the outputs fetched
+        as the kernel left them."""
         from repro.kernels import fused_ingest, fused_ingest_dense
 
         arity = rows.shape[1]
@@ -484,10 +489,12 @@ class StreamingJoinEngine:
                 zero_deltas if with_sketch else None
             )
 
+        rows32 = rows.astype(np.int32)
+        w_real = None
         if routes and self.config.fused_dynamic_routes:
             enc, w_real, k_pad = self._dense_routes(rel, routes)
             dest, rank, counts, cms = fused_ingest_dense(
-                jnp.asarray(rows.astype(np.int32)),
+                jnp.asarray(rows32),
                 enc,
                 sketch_cols=tuple(c for _, c in cols),
                 seeds=seeds,
@@ -496,15 +503,10 @@ class StreamingJoinEngine:
                 block=self.config.fused_block,
                 double_buffer=self.config.fused_double_buffer,
             )
-            # the dense kernel returns padded (N_pad, W_pad, k_pad) shapes
-            # so the executable survives replans; slice to real sizes here
-            n = rows.shape[0]
-            dest = np.asarray(dest)[:n, :w_real]
-            rank = np.asarray(rank)[:n, :w_real]
-            counts = np.asarray(counts)[:k]
+            h2d = rows32.nbytes + sum(a.nbytes for a in enc.values())
         else:
             dest, rank, counts, cms = fused_ingest(
-                jnp.asarray(rows.astype(np.int32)),
+                jnp.asarray(rows32),
                 routes=routes,
                 sketch_cols=tuple(c for _, c in cols),
                 seeds=seeds,
@@ -513,30 +515,72 @@ class StreamingJoinEngine:
                 block=self.config.fused_block,
                 double_buffer=self.config.fused_double_buffer,
             )
+            h2d = rows32.nbytes
+        # fetching waits for the kernel, then copies its outputs whole
+        with self.obs.span("route.fetch"):
+            fetched = tuple(
+                None if a is None else np.asarray(a)
+                for a in (dest, rank, counts, cms if with_sketch else None)
+            )
+        dest, rank, counts, cms = fetched
+        if moved is not None:
+            moved[0] += h2d
+            moved[1] += sum(a.nbytes for a in fetched if a is not None)
         routed = None
         if with_route:
-            dest, rank = np.asarray(dest), np.asarray(rank)
+            if w_real is not None:
+                # the dense kernel returns padded (N_pad, W_pad, k_pad)
+                # shapes so the executable survives replans; slice to the
+                # real sizes here
+                n = rows.shape[0]
+                dest, rank, counts = dest[:n, :w_real], rank[:n, :w_real], counts[:k]
             n, w = dest.shape
             flat_dest = dest.reshape(-1)
             flat_rank = rank.reshape(-1)
             flat_rows = np.broadcast_to(
-                rows.astype(np.int32)[:, None, :], (n, w, arity)
+                rows32[:, None, :], (n, w, arity)
             ).reshape(-1, arity)
             ok = flat_dest >= 0
             routed = _Routed(
                 flat_dest[ok].astype(np.int32),
                 flat_rows[ok],
                 flat_rank[ok],
-                np.asarray(counts).astype(np.int64),
+                counts.astype(np.int64),
             )
         deltas = None
         if with_sketch:
-            cms_np = np.asarray(cms) if cms is not None else None
             deltas = {
-                a: cms_np[i].astype(np.float64)
+                a: cms[i].astype(np.float64)
                 for i, (a, _) in enumerate(cols)
             }
         return routed, deltas
+
+    def _fused_batch_pass(
+        self, batch: dict[str, np.ndarray], with_sketch: bool
+    ) -> tuple[dict[str, _Routed], dict[tuple[str, str], np.ndarray]]:
+        """The batch's fused pass, one kernel call per relation, under the
+        ``route.fused`` span (which counts the bytes moved in traced
+        runs).  Returns (per relation, its emissions routed under the live
+        plan — none before the first plan; Count-Min increments keyed
+        ``(attr, rel_name)`` if ``with_sketch``)."""
+        has_plan = self.plan is not None
+        routes: dict[str, _Routed] = {}
+        deltas: dict[tuple[str, str], np.ndarray] = {}
+        with self.obs.span("route.fused") as span:
+            moved = [0, 0] if self.obs.tracer.enabled else None
+            for rel in self.query.relations:
+                routed, d = self._fused_pass(
+                    rel, batch[rel.name], with_route=has_plan,
+                    with_sketch=with_sketch, moved=moved,
+                )
+                for a, tbl in (d or {}).items():
+                    deltas[(a, rel.name)] = tbl
+                if routed is not None:
+                    routes[rel.name] = routed
+            if moved is not None:
+                span.set(h2d_bytes=moved[0], d2h_bytes=moved[1])
+        self.fused_batches += 1
+        return routes, deltas
 
     def _route_any(self, rel, rows: np.ndarray) -> _Routed:
         """Route one relation under the current plan — fused kernel or the
@@ -604,24 +648,25 @@ class StreamingJoinEngine:
         """Append a routed batch to a binned state.  With a fused-kernel
         pack plan the slot is ``occupancy + rank`` directly (no sort); the
         result is bit-identical to ``_scatter_into``."""
-        if routed.rank is None:
-            return self._scatter_into(state, routed.dest, routed.rows)
-        bins, valid, occup = state
-        if routed.dest.size == 0:
-            return state
-        new_occup = occup + routed.counts
-        cap = bins.shape[1]
-        cap_needed = int(new_occup.max())
-        if cap_needed > cap:
-            new_cap = _pow2(cap_needed)
-            bins = np.pad(bins, ((0, 0), (0, new_cap - cap), (0, 0)))
-            valid = np.pad(valid, ((0, 0), (0, new_cap - cap)))
-        else:
-            bins, valid = bins.copy(), valid.copy()
-        slots = routed.rank + occup[routed.dest]
-        bins[routed.dest, slots] = routed.rows
-        valid[routed.dest, slots] = True
-        return bins, valid, new_occup
+        with self.obs.span("join.scatter"):
+            if routed.rank is None:
+                return self._scatter_into(state, routed.dest, routed.rows)
+            bins, valid, occup = state
+            if routed.dest.size == 0:
+                return state
+            new_occup = occup + routed.counts
+            cap = bins.shape[1]
+            cap_needed = int(new_occup.max())
+            if cap_needed > cap:
+                new_cap = _pow2(cap_needed)
+                bins = np.pad(bins, ((0, 0), (0, new_cap - cap), (0, 0)))
+                valid = np.pad(valid, ((0, 0), (0, new_cap - cap)))
+            else:
+                bins, valid = bins.copy(), valid.copy()
+            slots = routed.rank + occup[routed.dest]
+            bins[routed.dest, slots] = routed.rows
+            valid[routed.dest, slots] = True
+            return bins, valid, new_occup
 
     def _rebuild_routed_state(self) -> int:
         """Re-route every retained batch under ``self.plan`` from scratch:
@@ -760,14 +805,16 @@ class StreamingJoinEngine:
         expired = {nm: self._routed_log[nm].pop(0) for nm in self._routed_log}
         for rel in self.query.relations:
             self._history[rel.name].pop(0)
-        survivors = {
-            nm: remove_prefix(self._state[nm], expired[nm].counts)
-            for nm in self._state
-        }
-        if self._delta_index is not None:
-            cnt, chk = self._retract_sorted(bid, expired)
-        else:
-            cnt, chk = self._retract_einsum(expired, survivors)
+        with self.obs.span("retention.compact"):
+            survivors = {
+                nm: remove_prefix(self._state[nm], expired[nm].counts)
+                for nm in self._state
+            }
+        with self.obs.span("retention.retract"):
+            if self._delta_index is not None:
+                cnt, chk = self._retract_sorted(bid, expired)
+            else:
+                cnt, chk = self._retract_einsum(expired, survivors)
         self._state.update(survivors)
         self.window_count -= cnt
         self.window_checksum = (self.window_checksum - chk) & _MASK32
@@ -1075,10 +1122,12 @@ class StreamingJoinEngine:
         for i, nm in enumerate(names):
             routed = new_routed[nm]
             if routed.dest.size:
-                cnt, chk = idx.probe(names[1 - i], nm, routed.dest, routed.rows)
+                with self.obs.span("join.probe"):
+                    cnt, chk = idx.probe(names[1 - i], nm, routed.dest, routed.rows)
                 d_count += cnt
                 d_checksum = (d_checksum + chk) & _MASK32
-            idx.append(nm, routed.dest, routed.rows, batch_id)
+            with self.obs.span("join.index_append"):
+                idx.append(nm, routed.dest, routed.rows, batch_id)
             self._state[nm] = self._scatter_any(self._state[nm], routed)
         return d_count, d_checksum
 
@@ -1120,16 +1169,17 @@ class StreamingJoinEngine:
         )
         names = [r.name for r in self.query.relations]
         d_count, d_checksum = 0, 0
-        for i, nm_i in enumerate(names):
-            if new_routed[nm_i].dest.size == 0:
-                continue  # ΔR_i empty -> term contributes nothing
-            bins, valids = {}, {}
-            for j, nm_j in enumerate(names):
-                key = "all" if j < i else ("new" if j == i else "old")
-                bins[nm_j], valids[nm_j] = variants[nm_j][key]
-            cnt, chk = join_fn(self.spec, bins, valids)
-            d_count += int(cnt)
-            d_checksum = (d_checksum + int(np.uint32(chk))) & _MASK32
+        with self.obs.span("join.probe"):
+            for i, nm_i in enumerate(names):
+                if new_routed[nm_i].dest.size == 0:
+                    continue  # ΔR_i empty -> term contributes nothing
+                bins, valids = {}, {}
+                for j, nm_j in enumerate(names):
+                    key = "all" if j < i else ("new" if j == i else "old")
+                    bins[nm_j], valids[nm_j] = variants[nm_j][key]
+                cnt, chk = join_fn(self.spec, bins, valids)
+                d_count += int(cnt)
+                d_checksum = (d_checksum + int(np.uint32(chk))) & _MASK32
         self._state.update(merged)
         return d_count, d_checksum
 
@@ -1155,25 +1205,22 @@ class StreamingJoinEngine:
         With ``config.obs`` enabled (DESIGN.md §10) the batch runs under a
         root ``ingest`` span with the lifecycle phases nested inside, the
         per-batch metrics land in the shared registry, and the returned
-        report's ``obs`` field carries the post-batch metrics + skew
-        snapshots (compare-excluded; the deterministic fields still take
-        part in the baseline-vs-fused parity assertions).
+        report's ``obs`` field carries the post-batch SkewScope snapshot
+        (compare-excluded; the deterministic fields still take part in
+        the baseline-vs-fused parity assertions).
         """
         obs = self.obs
         obs.tracer.set_batch(len(self.reports))
         t0 = time.perf_counter()
         with obs.span("ingest", args={"tenant": self.tenant} if obs.tracer.enabled else None):
             report = self._ingest_inner(batch, shared_deltas)
-        if obs.metrics.enabled or obs.skew is not None:
-            if obs.metrics.enabled:
-                self._record_batch_metrics(report, time.perf_counter() - t0)
-            payload: dict = {}
-            if obs.metrics.enabled:
-                payload["metrics"] = obs.metrics.snapshot()
             if obs.skew is not None:
-                payload["skew"] = obs.skew.snapshot().as_dict()
-            report = dataclasses.replace(report, obs=payload)
-            self.reports[-1] = report
+                with obs.span("obs.skewscope"):
+                    payload = {"skew": obs.skew.snapshot().as_dict()}
+                report = dataclasses.replace(report, obs=payload)
+                self.reports[-1] = report
+        if obs.metrics.enabled:
+            self._record_batch_metrics(report, time.perf_counter() - t0)
         return report
 
     def _record_batch_metrics(self, report: BatchReport, seconds: float) -> None:
@@ -1286,44 +1333,29 @@ class StreamingJoinEngine:
                 if a in rel.attrs
             }
             if self.config.fused_ingest:
-                has_plan = self.plan is not None
-                with self.obs.span("route.fused"):
-                    for rel in self.query.relations:
-                        routed, _ = self._fused_pass(
-                            rel, batch[rel.name], with_route=has_plan,
-                            with_sketch=False,
-                        )
-                        if routed is not None:
-                            spec_routes[rel.name] = routed
-                self.fused_batches += 1
+                spec_routes, _ = self._fused_batch_pass(batch, with_sketch=False)
             with self.obs.span("sketch.update", args={"shared": True}):
                 self.tracker.observe_absorbed(batch, picked)
         elif self.config.fused_ingest:
-            deltas: dict[tuple[str, str], np.ndarray] = {}
-            has_plan = self.plan is not None
             # route + sketch increment are ONE fused pass per relation
             # (DESIGN.md §7); the span covers both halves of the taxonomy
-            with self.obs.span("route.fused"):
-                for rel in self.query.relations:
-                    routed, d = self._fused_pass(
-                        rel, batch[rel.name], with_route=has_plan, with_sketch=True
-                    )
-                    if d is not None:
-                        for a, tbl in d.items():
-                            deltas[(a, rel.name)] = tbl
-                    if routed is not None:
-                        spec_routes[rel.name] = routed
+            spec_routes, deltas = self._fused_batch_pass(batch, with_sketch=True)
             with self.obs.span("sketch.update"):
                 self.tracker.observe_absorbed(batch, deltas)
-            self.fused_batches += 1
             self.sketch_ingest_calls += 1
         else:
             with self.obs.span("sketch.update"):
                 self.tracker.observe(batch)
             self.sketch_ingest_calls += 1
-        snapshot = self.tracker.snapshot(
-            self._threshold(), self.config.max_hh_per_attr
-        )
+        with self.obs.span("sketch.snapshot"):
+            snapshot = self.tracker.snapshot(
+                self._threshold(), self.config.max_hh_per_attr
+            )
+            pinned_rates = None if self.plan is None else {
+                (a, int(v)): float(self.tracker.rate_of(a, np.array([v]))[0])
+                for a, vals in self.plan.hh_values.items()
+                for v in np.asarray(vals).tolist()
+            }
         hh = {a: s.values for a, s in snapshot.items()}
 
         replanned, reason, migrated = False, "", 0
@@ -1339,11 +1371,6 @@ class StreamingJoinEngine:
                 migrated = self._install(plan, batch)
             replanned, reason = True, "initial plan"
         else:
-            pinned_rates = {
-                (a, int(v)): float(self.tracker.rate_of(a, np.array([v]))[0])
-                for a, vals in self.plan.hh_values.items()
-                for v in np.asarray(vals).tolist()
-            }
             with self.obs.span("drift.check"):
                 decision: DriftDecision = self.monitor.check(
                     self.plan, self.query, batch, snapshot, pinned_rates
@@ -1380,7 +1407,6 @@ class StreamingJoinEngine:
 
         # route the new batch under the (possibly fresh) plan
         new_routed, comm = {}, {}
-        skew = self.obs.skew
         with self.obs.span("route"):
             for rel in self.query.relations:
                 routed = spec_routes.get(rel.name)
@@ -1389,10 +1415,14 @@ class StreamingJoinEngine:
                 new_routed[rel.name] = routed
                 comm[rel.name] = int(routed.dest.size)
                 self._loads += routed.counts
-                if skew is not None:
-                    skew.record(rel.name, routed.counts)
+        skew = self.obs.skew
         if skew is not None:
-            skew.record_hh(*hh_hit_counts(self.query, batch, self.plan.hh_values))
+            with self.obs.span("obs.skewscope"):
+                for nm, routed in new_routed.items():
+                    skew.record(nm, routed.counts)
+                skew.record_hh(
+                    *hh_hit_counts(self.query, batch, self.plan.hh_values)
+                )
 
         bid = len(self.reports)
         with self.obs.span("join.delta"):
@@ -1406,47 +1436,48 @@ class StreamingJoinEngine:
         # raw rows are kept only for replan migration; the binned reducer
         # state was already folded by _delta_join.  The routed log feeds
         # retraction and is kept only under retention.
-        self._retained_ids.append(bid)
-        self._batch_ts.append(now)
-        for rel in self.query.relations:
-            self._history[rel.name].append(batch[rel.name])
-            if self.config.retention.enabled:
-                self._routed_log[rel.name].append(new_routed[rel.name])
+        with self.obs.span("report"):
+            self._retained_ids.append(bid)
+            self._batch_ts.append(now)
+            for rel in self.query.relations:
+                self._history[rel.name].append(batch[rel.name])
+                if self.config.retention.enabled:
+                    self._routed_log[rel.name].append(new_routed[rel.name])
 
-        carried, max_carried = carried_tuples(self._state)
-        report = BatchReport(
-            batch=bid,
-            plan_epoch=self.plan_epoch,
-            replanned=replanned,
-            drift_reason=reason,
-            delta_count=d_count,
-            total_count=self.total_count,
-            total_checksum=self.total_checksum,
-            comm_tuples=comm,
-            cumulative_comm=self.cumulative_comm,
-            migrated_tuples=migrated,
-            max_load=int(self._loads.max()) if self._loads.size else 0,
-            hh_values={
-                a: np.asarray(v).tolist() for a, v in self.plan.hh_values.items()
-            },
-            deferred=deferred,
-            shed=shed,
-            expired_batches=expired_n,
-            retracted_count=retracted,
-            window_count=self.window_count,
-            window_checksum=self.window_checksum,
-            carried_tuples=carried,
-            max_carried=max_carried,
-            drift_trigger=trigger,
-            drift_observed=observed,
-            drift_threshold=threshold,
-        )
-        self.reports.append(report)
-        self._log(
-            f"[stream] batch {report.batch}: +{d_count} results "
-            f"(total {self.total_count}), comm {report.total_comm}, "
-            f"hh {report.hh_values or '{}'}"
-        )
+            carried, max_carried = carried_tuples(self._state)
+            report = BatchReport(
+                batch=bid,
+                plan_epoch=self.plan_epoch,
+                replanned=replanned,
+                drift_reason=reason,
+                delta_count=d_count,
+                total_count=self.total_count,
+                total_checksum=self.total_checksum,
+                comm_tuples=comm,
+                cumulative_comm=self.cumulative_comm,
+                migrated_tuples=migrated,
+                max_load=int(self._loads.max()) if self._loads.size else 0,
+                hh_values={
+                    a: np.asarray(v).tolist() for a, v in self.plan.hh_values.items()
+                },
+                deferred=deferred,
+                shed=shed,
+                expired_batches=expired_n,
+                retracted_count=retracted,
+                window_count=self.window_count,
+                window_checksum=self.window_checksum,
+                carried_tuples=carried,
+                max_carried=max_carried,
+                drift_trigger=trigger,
+                drift_observed=observed,
+                drift_threshold=threshold,
+            )
+            self.reports.append(report)
+            self._log(
+                f"[stream] batch {report.batch}: +{d_count} results "
+                f"(total {self.total_count}), comm {report.total_comm}, "
+                f"hh {report.hh_values or '{}'}"
+            )
         return report
 
     def history_data(self) -> dict[str, np.ndarray]:

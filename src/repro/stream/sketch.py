@@ -30,6 +30,7 @@ from repro.core.dominance import share_attributes
 from repro.core.heavy_hitters import CountMinSketch
 from repro.core.schema import JoinQuery
 from repro.mapreduce.hashing import bucket_np
+from repro.obs import NULL_OBS, Observability
 
 
 def _row_seeds(seed: int, depth: int) -> tuple[int, ...]:
@@ -246,8 +247,10 @@ class StreamHHTracker:
         decay: float = 0.5,
         seed: int = 0,
         use_device_sketch: bool = False,
+        obs: Observability = NULL_OBS,
     ):
         self.query = query
+        self.obs = obs
         self.attrs = share_attributes(query)
         self.decay = float(decay)
         self.width = int(width)
@@ -262,15 +265,28 @@ class StreamHHTracker:
                 )
         self.batches = 0
 
+    def _columns(self, batch: dict[str, np.ndarray]):
+        """(attr, relation name, join column) for every sketched column."""
+        return [
+            (a, rel.name, np.asarray(batch[rel.name])[:, rel.index_of(a)])
+            for a in self.attrs
+            for rel in self.query.relations_of(a)
+        ]
+
+    def _observe_candidates(self, columns) -> None:
+        with self.obs.span("sketch.candidates"):
+            for a in self.attrs:
+                self._ss[a].decay(self.decay)
+            for a, _, col in columns:
+                self._ss[a].update(col)
+
     def observe(self, batch: dict[str, np.ndarray]) -> None:
-        for cms in self._cms.values():
-            cms.step()
-        for a in self.attrs:
-            self._ss[a].decay(self.decay)
-        for a in self.attrs:
-            for rel in self.query.relations_of(a):
-                col = np.asarray(batch[rel.name])[:, rel.index_of(a)]
-                cms = self._cms[(a, rel.name)]
+        columns = self._columns(batch)
+        with self.obs.span("sketch.cms"):
+            for cms in self._cms.values():
+                cms.step()
+            for a, rel_name, col in columns:
+                cms = self._cms[(a, rel_name)]
                 if self.use_device_sketch and col.size:
                     import jax.numpy as jnp
 
@@ -284,7 +300,7 @@ class StreamHHTracker:
                     cms.absorb(delta.astype(np.float64), col.size)
                 else:
                     cms.update(col)
-                self._ss[a].update(col)
+        self._observe_candidates(columns)
         self.batches += 1
 
     def observe_absorbed(
@@ -299,20 +315,18 @@ class StreamHHTracker:
         (``kernels.ingest_fused``), which shares this tracker's ``seeds``
         so tables stay bit-identical to the host ``observe`` path
         (integer counts are exact in float64).  SpaceSaving candidate
-        tracking still runs host-side: it is a tiny dict update and needs
-        the raw values, which the sketch buckets discard.
+        tracking still runs host-side: it needs the raw values, which the
+        sketch buckets discard.
         """
-        for cms in self._cms.values():
-            cms.step()
-        for a in self.attrs:
-            self._ss[a].decay(self.decay)
-        for a in self.attrs:
-            for rel in self.query.relations_of(a):
-                col = np.asarray(batch[rel.name])[:, rel.index_of(a)]
-                self._cms[(a, rel.name)].absorb(
-                    np.asarray(deltas[(a, rel.name)], dtype=np.float64), col.size
+        columns = self._columns(batch)
+        with self.obs.span("sketch.cms"):
+            for cms in self._cms.values():
+                cms.step()
+            for a, rel_name, col in columns:
+                self._cms[(a, rel_name)].absorb(
+                    np.asarray(deltas[(a, rel_name)], dtype=np.float64), col.size
                 )
-                self._ss[a].update(col)
+        self._observe_candidates(columns)
         self.batches += 1
 
     def candidates_of(self, attr: str) -> tuple[np.ndarray, np.ndarray]:

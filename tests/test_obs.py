@@ -15,8 +15,13 @@ The contracts, in the order the acceptance criteria state them:
 """
 from __future__ import annotations
 
+import glob
 import json
+import os
+import tracemalloc
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -30,8 +35,11 @@ from repro.obs import (
     ObsPolicy,
     Tracer,
 )
+from repro.kernels import fused_ingest_dense
+from repro.mapreduce.keys import static_route_table
 from repro.stream import (
     MultiQueryEngine,
+    RetentionPolicy,
     StreamConfig,
     StreamingJoinEngine,
     TenancyPolicy,
@@ -91,8 +99,8 @@ def test_span_nesting_and_ordering():
     assert inner["ts"] + inner["dur"] < outer["ts"] + outer["dur"]
     assert inner["args"]["k"] == 1
     # span ids are batch-scoped and sequential
-    assert outer["args"]["span_id"] == "0.1"
-    assert inner["args"]["span_id"] == "0.2"
+    assert outer["args"]["span_id"] == "0:1"
+    assert inner["args"]["span_id"] == "0:2"
     assert by_name["mark"]["ph"] == "i"
 
 
@@ -256,9 +264,158 @@ def test_skew_report_surfaces_in_batch_report():
     rep = eng.reports[-1]
     assert rep.obs is not None
     assert rep.obs["skew"]["total_reducers"] == eng.plan.total_reducers
-    assert rep.obs["metrics"]["counters"]["stream_batches_total"] == 4
+    assert set(rep.obs) == {"skew"}
+    # the metrics stay in the engine's registry, not in every report
+    assert eng.obs.metrics.snapshot()["counters"]["stream_batches_total"] == 4
     # drift decision surfaces trigger + observed/threshold on the report
     replanned = [r for r in eng.reports if r.replanned and r.batch > 0]
     for r in replanned:
         assert r.drift_trigger in {"overload", "comm", "faded_pin"}
         assert r.drift_observed > r.drift_threshold > 0.0
+
+
+# ---- leaf spans, parent ids, counts and the profiler mirror ----------------
+
+# every leaf span and the spans it may open inside (None: a root span)
+LEAF_PARENTS = {
+    "sketch.cms": {"sketch.update"},
+    "sketch.candidates": {"sketch.update"},
+    "sketch.snapshot": {"ingest"},
+    "report": {"ingest"},
+    "obs.skewscope": {"ingest"},
+    "join.probe": {"join.delta"},
+    "join.index_append": {"join.delta"},
+    "join.scatter": {"join.delta", "replan.migrate"},
+    "retention.retract": {"retention.expire"},
+    "retention.compact": {"retention.expire"},
+    "route.fetch": {"route.fused", "replan.compile", "replan.migrate", "route"},
+}
+
+
+def _fused_engine(n_batches=5, policy=ObsPolicy(trace=True, skewscope=True)):
+    rng = np.random.default_rng(5)
+    eng = StreamingJoinEngine(
+        two_way(),
+        StreamConfig(
+            q=60, decay=0.5, load_factor=2.0, fused_ingest=True, obs=policy,
+            retention=RetentionPolicy(window_batches=2),
+        ),
+    )
+    batches = [_zipf_batch(rng, n_r=300, n_s=80) for _ in range(n_batches)]
+    for b in batches:
+        eng.ingest(b)
+    return eng, batches
+
+
+def test_parent_ids_and_counts_reach_the_events():
+    tr = Tracer(enabled=True)
+    tr.set_batch(3)
+    with tr.span("outer") as outer:
+        with tr.span("inner") as inner:
+            inner.set(h2d_bytes=10)
+            inner.set(d2h_bytes=20)
+        tr.instant("mark")
+        outer.set(rows=5)
+    by_name = {e["name"]: e["args"] for e in tr.events}
+    assert by_name["outer"]["parent"] is None
+    assert by_name["inner"]["parent"] == by_name["outer"]["span_id"] == "3:1"
+    assert by_name["mark"]["parent"] == "3:1"
+    assert (by_name["inner"]["h2d_bytes"], by_name["inner"]["d2h_bytes"]) == (10, 20)
+    assert by_name["outer"]["rows"] == 5
+
+
+def test_null_span_set_allocates_nothing():
+    tr = Tracer(enabled=False)
+
+    def plain():
+        with tr.span("route.fused"):
+            pass
+
+    def counted():
+        with tr.span("route.fused") as span:
+            span.set(h2d_bytes=1, d2h_bytes=2)
+
+    peaks = []
+    for hot in (plain, counted):
+        hot()
+        tracemalloc.start()
+        for _ in range(1000):
+            hot()
+        current, peak = tracemalloc.get_traced_memory()
+        tracemalloc.stop()
+        peaks.append(peak)
+        assert current < 256  # nothing accumulates across a thousand calls
+    assert peaks[1] == peaks[0]
+    assert NULL_SPAN.__slots__ == ()
+
+
+def test_fused_stream_emits_every_leaf_span_inside_its_parent():
+    eng, _ = _fused_engine()
+    events = [e for e in eng.obs.tracer.events if e["ph"] == "X"]
+    names = {e["args"]["span_id"]: e["name"] for e in events}
+    seen = set()
+    for e in events:
+        if e["name"] in LEAF_PARENTS:
+            parent = names.get(e["args"]["parent"])
+            assert parent in LEAF_PARENTS[e["name"]], (e["name"], parent)
+            seen.add(e["name"])
+    assert seen == set(LEAF_PARENTS)
+    # one root ingest per batch, and every other span's parent is known
+    assert [e["args"]["batch"] for e in events if e["name"] == "ingest"] == list(range(5))
+    for e in events:
+        assert e["args"]["parent"] is None or e["args"]["parent"] in names
+
+
+def test_route_fused_counts_the_padded_outputs_it_fetches():
+    eng, batches = _fused_engine()
+    assert not any(r.replanned for r in eng.reports[1:])  # one plan throughout
+    last = [
+        e for e in eng.obs.tracer.events
+        if e["name"] == "route.fused" and e["args"]["batch"] == len(batches) - 1
+    ]
+    assert len(last) == 1
+    h2d = d2h = 0
+    for rel in eng.query.relations:
+        rows = batches[-1][rel.name].astype(np.int32)
+        enc, _, k_pad = eng._dense_routes(rel, static_route_table(eng.plan, rel))
+        outs = fused_ingest_dense(
+            jnp.asarray(rows), enc,
+            sketch_cols=tuple(c for _, c in eng._sketch_cols[rel.name]),
+            seeds=eng.tracker.seeds, width=eng.config.sketch_width, k_pad=k_pad,
+            block=eng.config.fused_block, double_buffer=eng.config.fused_double_buffer,
+        )
+        h2d += rows.nbytes + sum(a.nbytes for a in enc.values())
+        d2h += sum(np.asarray(o).nbytes for o in outs)
+    assert last[0]["args"]["h2d_bytes"] == h2d
+    assert last[0]["args"]["d2h_bytes"] == d2h
+    # padded: more than the real emissions' 8 B (id + rank) each
+    assert d2h > 8 * sum(eng.reports[-1].comm_tuples.values())
+
+
+def test_spans_appear_in_the_profiler_trace(tmp_path):
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng, _ = _fused_engine(n_batches=3)
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"), recursive=True))[-1]
+    mirrored, other = [], set()
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("obs/"):
+                    mirrored.append((e.name, dict(e.stats)))
+                else:
+                    other.add(e.name)
+    names = {n for n, _ in mirrored}
+    assert {f"obs/{n}" for n in eng.obs.tracer.span_names()} <= names
+    assert {"obs/ingest", "obs/sketch.candidates", "obs/route.fetch"} <= names
+    for name, stats in mirrored:
+        assert stats["batch"] in range(3), (name, stats)
+        assert isinstance(stats["span_id"], str) and stats["span_id"].startswith(f"{stats['batch']}:")
+    # the mirror never takes the names a caller's own annotations use
+    assert not {"ingest", "window"} & other
