@@ -41,6 +41,7 @@ from repro.stream import (
     MultiQueryEngine,
     RetentionPolicy,
     StreamConfig,
+    StreamHHTracker,
     StreamingJoinEngine,
     TenancyPolicy,
     TenantSpec,
@@ -390,6 +391,23 @@ def test_route_fused_counts_the_padded_outputs_it_fetches():
     assert last[0]["args"]["d2h_bytes"] == d2h
     # padded: more than the real emissions' 8 B (id + rank) each
     assert d2h > 8 * sum(eng.reports[-1].comm_tuples.values())
+
+
+def test_sketch_candidates_counts_folds_and_evictions():
+    obs = Observability(ObsPolicy(trace=True))
+    tracker = StreamHHTracker(two_way(), capacity=64, obs=obs)
+    b_r = np.arange(1000, 1200)  # 200 values once: 64 fill, 136 evict
+    b_s = np.concatenate([np.repeat(np.arange(5), 2), np.arange(2000, 2100)])
+    tracker.observe({
+        "R": np.stack([np.zeros_like(b_r), b_r], 1),
+        "S": np.stack([b_s, np.zeros_like(b_s)], 1),
+    })
+    (event,) = [e for e in obs.tracer.events if e["name"] == "sketch.candidates"]
+    # S: the five count-2 values are a run too short for the run path, so
+    # each evicts by the victim scan; its 100 count-1 values take the run path
+    assert event["args"]["distinct"] == 200 + 105
+    assert event["args"]["evictions"] == 136 + 105
+    assert event["args"]["run_evictions"] == 136 + 100
 
 
 def test_spans_appear_in_the_profiler_trace(tmp_path):

@@ -140,6 +140,127 @@ def test_space_saving_retains_heavy_values():
         assert c >= true.get(v, 0)  # overestimates only
 
 
+def _per_value_update(ss, keys):
+    """``SpaceSaving.update`` as it was before equal-count runs were folded
+    together: one O(capacity) victim scan per newcomer.  The oracle."""
+    keys = np.asarray(keys, dtype=np.int64)
+    if keys.size == 0:
+        return
+    vals, cnts = np.unique(keys, return_counts=True)
+    # largest first so evictions never displace a bigger newcomer
+    order = np.argsort(-cnts, kind="stable")
+    for v, c in zip(vals[order].tolist(), cnts[order].tolist()):
+        if v in ss.counts:
+            ss.counts[v] += c
+        elif len(ss.counts) < ss.capacity:
+            ss.counts[v] = float(c)
+            ss.errors[v] = 0.0
+        else:
+            victim = min(ss.counts, key=ss.counts.__getitem__)
+            floor = ss.counts.pop(victim)
+            ss.errors.pop(victim)
+            ss.counts[v] = floor + c
+            ss.errors[v] = floor
+
+
+def _assert_same_summary(got, want):
+    assert list(got.counts.items()) == list(want.counts.items())
+    assert list(got.errors.items()) == list(want.errors.items())
+    for g, w in zip(got.candidates(), want.candidates()):
+        np.testing.assert_array_equal(g, w)
+
+
+def _paper_hh_column(rng, n, domain=1 << 16):
+    return np.where(rng.random(n) < 0.1, 7, rng.integers(0, domain, n))
+
+
+def _ss_stream(case):
+    """(capacity, decay, state to start from or None, batches) per case."""
+    rng = np.random.default_rng(31)
+    if case == "not_full":  # fills 64 counters mid-run, then evicts
+        return 64, 0.5, None, [rng.integers(0, 1000, 40), np.arange(5000, 5200)]
+    if case == "capacity_1":
+        return 1, 0.5, None, [rng.integers(0, 300, 400) for _ in range(4)]
+    if case == "equal_counts":
+        return 16, 0.5, None, [np.repeat(rng.permutation(800)[:300], 3)] * 3
+    if case == "monitored_at_run_start":
+        # 5 goes before its turn (1 evicts it), 35 is still there at its turn
+        start = {
+            "values": np.array([5, 35, 900, 901]),
+            "counts": np.array([0.5, 1000.0, 0.25, 0.75]),
+            "errors": np.array([0.0, 3.0, 0.0, 0.5]),
+        }
+        return 4, 1.0, start, [np.arange(64)]
+    if case == "tied_with_the_rest":
+        # 3 and 4 cycle up one a newcomer; after 32 newcomers 20 ties the
+        # pushed 20 and, being older, goes first: 102 is the 34th's victim
+        start = {
+            "values": np.array([100, 101, 102]),
+            "counts": np.array([3.0, 4.0, 20.0]),
+            "errors": np.zeros(3),
+        }
+        return 3, 1.0, start, [np.arange(34)]
+    if case == "decay_rounding":  # 64 batches, counts near 1e5 at decay 0.5
+        heavy = np.repeat([11, 12, 13], [50_000, 30_000, 20_000])
+        return 32, 0.5, None, [
+            np.concatenate([heavy, rng.integers(0, 1 << 16, 1500)]) for _ in range(64)
+        ]
+    if case == "paper_hh_shaped":  # 10 % on one value, the rest over 2^16
+        return 64, 0.5, None, [
+            _paper_hh_column(rng, n) for _ in range(6) for n in (12_500, 1_250)
+        ]
+    if case == "empty_batch":
+        return 8, 0.5, None, [
+            rng.integers(0, 5000, 300), np.empty(0, np.int64), rng.integers(0, 5000, 300)
+        ]
+    raise ValueError(case)
+
+
+SS_CASES = [
+    "not_full", "capacity_1", "equal_counts", "monitored_at_run_start",
+    "tied_with_the_rest", "decay_rounding", "paper_hh_shaped", "empty_batch",
+]
+
+
+@pytest.mark.parametrize("case", SS_CASES)
+def test_space_saving_update_is_bit_identical_to_the_per_value_loop(case):
+    capacity, decay, start, batches = _ss_stream(case)
+    got, want = SpaceSaving(capacity), SpaceSaving(capacity)
+    if start is not None:
+        got.load_state_dict(start)
+        want.load_state_dict(start)
+    run_evictions = 0
+    for keys in batches:
+        got.decay(decay)
+        want.decay(decay)
+        distinct, evictions, on_run_path = got.update(keys)
+        _per_value_update(want, keys)
+        _assert_same_summary(got, want)
+        assert distinct == np.unique(keys).size
+        assert on_run_path <= evictions <= distinct
+        run_evictions += on_run_path
+    assert run_evictions > 0  # every case takes the run path somewhere
+    if case == "monitored_at_run_start":
+        assert got.counts[35] == 1001.0
+    if case == "tied_with_the_rest":
+        assert 102 not in got.counts
+
+
+def test_space_saving_checkpoint_mid_stream_resumes_bit_identically():
+    rng = np.random.default_rng(32)
+    batches = [_paper_hh_column(rng, 6_000) for _ in range(8)]
+    straight, resumed = SpaceSaving(64), None
+    for i, keys in enumerate(batches):
+        if i == 4:
+            resumed = SpaceSaving(64)
+            resumed.load_state_dict(straight.state_dict())
+        for ss in (straight, resumed) if resumed else (straight,):
+            ss.decay(0.5)
+            ss.update(keys)
+        if resumed:
+            _assert_same_summary(resumed, straight)
+
+
 def test_tracker_follows_drift():
     rng = np.random.default_rng(9)
     tracker = StreamHHTracker(two_way(), decay=0.5, seed=0)
