@@ -1,4 +1,6 @@
-"""One run of one cell: set-up, the measured window, the check, the result.
+"""One run of one stream cell: set-up, the measured window, the check, the
+result.  The helpers that end a run (the peak memory, the trace, the
+result object) serve the job path (``job.py``) as well.
 
 Set-up builds the engine through the public API, makes the batches from
 the seed and ingests the warm-up batches, which fill the retained window
@@ -262,6 +264,86 @@ def check(cell: Cell, pool: Pool, batches: list[Batch], window: int) -> dict:
     }
 
 
+def start_trace() -> str:
+    """Start the profiler on a fresh directory and return it."""
+    import jax
+
+    trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    return trace_dir
+
+
+def stop_trace(trace_dir: str, n_devices: int) -> TraceView | None:
+    """Stop the profiler, reduce its trace and remove the directory."""
+    import jax
+
+    jax.profiler.stop_trace()
+    view = _trace_view(trace_dir, n_devices)
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    return view
+
+
+def obs_spans(program) -> list[dict]:
+    """The events of the program's ``repro.obs`` tracer, if it has one."""
+    tracer = getattr(getattr(program, "obs", None), "tracer", None)
+    return list(getattr(tracer, "events", []) or [])
+
+
+def peak_bytes(devices: list) -> int:
+    """The peak memory in use on the fullest of ``devices``."""
+    peak = 0
+    for d in devices:
+        stats = d.memory_stats() or {}
+        peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
+    return peak
+
+
+def result(
+    run: Run, checks: dict, failed: int, first_bad: str | None, *,
+    devices: list, peak: int, window_compiles: int, trace: bool,
+) -> dict:
+    """The result object of a run's last line: the cell's metrics read
+    from ``run``, and ``checks``, each number compared beside its limit."""
+    metrics = {}
+    for m in run.cell.per_layer if trace else run.cell.end_to_end:
+        value = load_reader(m["name"])(run)
+        if value is not None:
+            metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
+    correct = bool(run.batches) and all(c["value"] <= c["limit"] for c in checks.values())
+    if first_bad:
+        log(f"first mismatch: {first_bad}")
+    d0 = devices[0]
+    device = {
+        "platform": d0.platform, "kind": d0.device_kind, "count": len(devices),
+        "memory_peak_bytes": peak,
+    }
+    out = {
+        "correct": correct,
+        "attempted": len(run.batches),
+        "failed": failed,
+        "metrics": metrics,
+        "device": device,
+    }
+    view = run.trace
+    if trace and view is not None:
+        device["busy_s"] = view.busy_s
+        device["window_s"] = view.window_s
+        out["breakdown"] = {
+            "device_ops": [[n, t / 1e9] for n, t in xtrace.top_ops(view.ops, 10)],
+            "idle_gaps": [
+                [n, t / 1e9]
+                for n, t in xtrace.idle_gaps(view.ops, view.lo, view.hi, view.spans, 10)
+            ],
+        }
+    out["window_compiles"] = window_compiles
+    out["checks"] = checks
+    for name, c in checks.items():
+        log(f"check {name}: {c['value']} (limit {c['limit']})")
+    return out
+
+
 def run_cell(
     cell: Cell,
     seed: int,
@@ -273,9 +355,7 @@ def run_cell(
     make_engine: Callable | None = None,
     clock: Callable[[], float] = time.perf_counter,
 ) -> dict:
-    """Run one cell and return the result object of its last line."""
-    import jax
-
+    """Run one stream cell and return the result object of its last line."""
     meter = CompileMeter()
     config = stream_config(cell, trace)
     window = config.retention.window_batches
@@ -294,12 +374,7 @@ def run_cell(
         f"({meter.seconds:.3f} s), cache hits {meter.hits}, misses {meter.misses}"
     )
 
-    trace_dir = None
-    if trace:
-        trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-")
-        options = jax.profiler.ProfileOptions()
-        options.python_tracer_level = 0
-        jax.profiler.start_trace(trace_dir, profiler_options=options)
+    trace_dir = start_trace() if trace else None
     compiles_before, compile_s_before = meter.compiles, meter.seconds
     setup_s = clock() - t_process
     if batches[-1].error is None:
@@ -313,19 +388,11 @@ def run_cell(
         f"{window_compiles} ({meter.seconds - compile_s_before:.3f} s)"
     )
 
-    peak = 0
-    for d in devices:
-        stats = d.memory_stats() or {}
-        peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
-    view = None
-    if trace:
-        jax.profiler.stop_trace()
-        view = _trace_view(trace_dir, len(devices))
-        shutil.rmtree(trace_dir, ignore_errors=True)
+    peak = peak_bytes(devices)
+    view = stop_trace(trace_dir, len(devices)) if trace else None
 
-    tracer = getattr(getattr(engine, "obs", None), "tracer", None)
-    spans = list(getattr(tracer, "events", []) or [])
-    del engine, tracer
+    spans = obs_spans(engine)
+    del engine
     gc.collect()
 
     result_check = check(cell, pool, batches, window)
@@ -337,12 +404,6 @@ def run_cell(
         sketch_cells=config.sketch_depth * config.sketch_width,
         pool=pool,
     )
-    metrics = {}
-    for m in cell.per_layer if trace else cell.end_to_end:
-        value = load_reader(m["name"])(run)
-        if value is not None:
-            metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
-
     checks = {
         "delta_count_mismatches": {"value": result_check["delta_count_mismatches"], "limit": 0},
         "window_fingerprint_mismatches": {
@@ -350,33 +411,7 @@ def run_cell(
         },
         "failed_batches": {"value": result_check["failed"], "limit": 0},
     }
-    correct = bool(batches) and all(c["value"] <= c["limit"] for c in checks.values())
-    if result_check["first_bad"]:
-        log(f"first mismatch: {result_check['first_bad']}")
-    d0 = devices[0]
-    device = {
-        "platform": d0.platform, "kind": d0.device_kind, "count": len(devices),
-        "memory_peak_bytes": peak,
-    }
-    out = {
-        "correct": correct,
-        "attempted": len(batches),
-        "failed": result_check["failed"],
-        "metrics": metrics,
-        "device": device,
-    }
-    if trace and view is not None:
-        device["busy_s"] = view.busy_s
-        device["window_s"] = view.window_s
-        out["breakdown"] = {
-            "device_ops": [[n, t / 1e9] for n, t in xtrace.top_ops(view.ops, 10)],
-            "idle_gaps": [
-                [n, t / 1e9]
-                for n, t in xtrace.idle_gaps(view.ops, view.lo, view.hi, view.spans, 10)
-            ],
-        }
-    out["window_compiles"] = window_compiles
-    out["checks"] = checks
-    for name, c in checks.items():
-        log(f"check {name}: {c['value']} (limit {c['limit']})")
-    return out
+    return result(
+        run, checks, result_check["failed"], result_check["first_bad"],
+        devices=devices, peak=peak, window_compiles=window_compiles, trace=trace,
+    )

@@ -7,10 +7,14 @@
   relation)``;
 * its traffic mix: ``traffic/<traffic>.json`` beside this package;
 * each metric: ``metrics/<metric>.py`` beside this package, a reader with
-  ``read(run) -> float | None``.
+  ``read(run) -> float | None``;
+* each control of the job path (``run.py --control <name>`` on a job
+  cell): ``controls/<name>.py`` beside this package, a factory
+  ``make_program(cell, devices, settings, trace)`` of what runs in the
+  batch entry's place.
 
-Adding a cell, a configuration, a key distribution, a mix or a metric is
-adding files and entries; nothing here names one.
+Adding a cell, a configuration, a key distribution, a mix, a metric or a
+control is adding files and entries; nothing here names one.
 """
 from __future__ import annotations
 
@@ -76,6 +80,10 @@ def key_file(kind: str, bench_dir: Path = BENCH_DIR) -> Path:
     return bench_dir / "keys" / f"{kind}.py"
 
 
+def control_file(name: str, bench_dir: Path = BENCH_DIR) -> Path:
+    return bench_dir / "controls" / f"{name}.py"
+
+
 def _load(path: Path, module_name: str):
     spec = importlib.util.spec_from_file_location(module_name, path)
     module = importlib.util.module_from_spec(spec)
@@ -91,3 +99,8 @@ def load_reader(name: str, bench_dir: Path = BENCH_DIR):
 def load_key_column(kind: str, bench_dir: Path = BENCH_DIR):
     """The ``column`` function of ``keys/<kind>.py``."""
     return _load(key_file(kind, bench_dir), f"chipbench_keys_{kind}").column
+
+
+def load_control(name: str, bench_dir: Path = BENCH_DIR):
+    """The ``make_program`` factory of ``controls/<name>.py``."""
+    return _load(control_file(name, bench_dir), f"chipbench_control_{name}").make_program
