@@ -14,6 +14,8 @@ from typing import Mapping
 
 import numpy as np
 
+from repro.obs import NULL_OBS, Observability
+
 from .dominance import share_attributes
 from .residual import (
     Combination,
@@ -117,27 +119,37 @@ def plan_shares_skew(
     max_hh_per_attr: int = 8,
     k_max: int = 1 << 22,
     prune: bool = True,
+    obs: Observability = NULL_OBS,
 ) -> SharesSkewPlan:
     """Stages 1-3 of SharesSkew (§5.2): detect HHs, prune subsumed values,
     enumerate residual joins, and solve each residual's shares under the
-    per-reducer capacity q."""
+    per-reducer capacity q.
+
+    With a tracing ``obs`` the stages are spans (DESIGN.md §10):
+    ``plan.detect`` (heavy-hitter detection and pruning, counting
+    ``heavy_hitters``) and ``plan.solve`` (residual enumeration and share
+    solving, counting ``residuals`` and ``reducers``)."""
     threshold = float(hh_threshold if hh_threshold is not None else q)
-    candidates = share_attributes(query)  # §4.1: HHs only for non-dominated
-    hh = detect_heavy_hitters(query, data, threshold, candidates, max_hh_per_attr)
-    if prune and hh:
-        hh, _, _ = prune_by_subsumption(query, data, hh, q, k_max)
+    with obs.span("plan.detect", cat="job") as span:
+        candidates = share_attributes(query)  # §4.1: HHs only for non-dominated
+        hh = detect_heavy_hitters(query, data, threshold, candidates, max_hh_per_attr)
+        if prune and hh:
+            hh, _, _ = prune_by_subsumption(query, data, hh, q, k_max)
+        span.set(heavy_hitters=sum(len(v) for v in hh.values()))
 
     residuals: list[ResidualPlan] = []
     offset = 0
-    for combo in enumerate_combinations(hh):
-        sizes = relevant_sizes(query, data, combo, hh)
-        if any(s == 0 for s in sizes.values()):
-            continue  # empty residual join -> contributes no output
-        pinned = frozenset(combo.pinned)
-        k, sol = solve_k_for_capacity(query, sizes, q, pinned, k_max)
-        rp = ResidualPlan(combo, sizes, k, sol, offset)
-        residuals.append(rp)
-        offset += rp.num_reducers
+    with obs.span("plan.solve", cat="job") as span:
+        for combo in enumerate_combinations(hh):
+            sizes = relevant_sizes(query, data, combo, hh)
+            if any(s == 0 for s in sizes.values()):
+                continue  # empty residual join -> contributes no output
+            pinned = frozenset(combo.pinned)
+            k, sol = solve_k_for_capacity(query, sizes, q, pinned, k_max)
+            rp = ResidualPlan(combo, sizes, k, sol, offset)
+            residuals.append(rp)
+            offset += rp.num_reducers
+        span.set(residuals=len(residuals), reducers=offset)
     return SharesSkewPlan(query, q, hh, tuple(residuals))
 
 

@@ -20,6 +20,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.planner import SharesSkewPlan
 from repro.core.schema import JoinQuery
+from repro.obs import NULL_OBS, Observability
 
 from .executor import JoinResult, _bin_cap, predicted_comm
 from .keys import map_phase
@@ -35,6 +36,17 @@ def _pad_shard(arr: np.ndarray, d: int) -> tuple[np.ndarray, np.ndarray]:
     mask = np.zeros(n_pad, dtype=bool)
     mask[:n] = True
     return out, mask
+
+
+def route_caps(plan: SharesSkewPlan, d: int, route_cap_factor: float) -> dict[str, int]:
+    """Per relation, the static capacity of each device's send buffer to
+    each other device: the plan's exact emissions spread evenly over the
+    ``d * d`` pairs of devices, times ``route_cap_factor``, plus slack."""
+    pred = predicted_comm(plan)
+    return {
+        name: max(32, int(math.ceil(pred[name] / (d * d) * route_cap_factor)) + 16)
+        for name in pred
+    }
 
 
 def distributed_program(
@@ -60,11 +72,7 @@ def distributed_program(
     cap = _bin_cap(plan, cap_factor)
     spec = LocalJoinSpec.from_query(query)
 
-    pred = predicted_comm(plan)
-    route_caps = {
-        name: max(32, int(math.ceil(pred[name] / (d * d) * route_cap_factor)) + 16)
-        for name in pred
-    }
+    caps = route_caps(plan, d, route_cap_factor)
 
     def stage(rows_list, mask_list):
         my_dev = jax.lax.axis_index(axis_name)
@@ -73,7 +81,7 @@ def distributed_program(
         comm = []
         overflow = jnp.int32(0)
         for rel, rows, rowmask in zip(query.relations, rows_list, mask_list):
-            rcap = route_caps[rel.name]
+            rcap = caps[rel.name]
             dest = map_phase(plan, rel, rows)  # [n_loc, W]
             dest = jnp.where(rowmask[:, None], dest, jnp.int32(-1))
             n, w = dest.shape
@@ -126,7 +134,25 @@ def run_distributed(
     axis_name: str = "shuffle",
     cap_factor: float = 3.0,
     route_cap_factor: float = 3.0,
+    obs: Observability = NULL_OBS,
 ) -> JoinResult:
+    """Run the plan as one MapReduce round on ``mesh`` (all devices by
+    default): map, one ``all_to_all`` over ``axis_name``, reduce.
+
+    The round is four phases, each a span on a tracing ``obs`` (DESIGN.md
+    §10):
+
+    * ``shuffle.stage`` — pad each relation to a multiple of the devices
+      and copy rows and masks to the device (counts ``h2d_bytes``);
+    * ``shuffle.lower`` — build ``distributed_program`` and lower and
+      compile it explicitly (``.lower(...).compile()``: the executable a
+      call of the jitted program would build, found in the persistent
+      compilation cache where it is there);
+    * ``shuffle.execute`` — run it and wait for its outputs;
+    * ``shuffle.fetch`` — copy the outputs to the host and build the
+      ``JoinResult`` (counts ``d2h_bytes``, ``comm_tuples`` summed over
+      relations, ``max_load``, ``total_load``, ``reducers``, ``overflow``).
+    """
     if not plan.residuals:  # some relation is empty -> join is empty
         return JoinResult(
             count=0,
@@ -141,22 +167,38 @@ def run_distributed(
     d = mesh.shape[axis_name]
     k = plan.total_reducers
     rel_order = [r.name for r in query.relations]
-    padded, masks = {}, {}
-    for name in rel_order:
-        arr = np.asarray(data[name], dtype=np.int32)
-        padded[name], masks[name] = _pad_shard(arr, d)
+    with obs.span("shuffle.stage", cat="job") as span:
+        staged = [
+            _pad_shard(np.asarray(data[nm], dtype=np.int32), d) for nm in rel_order
+        ]
+        rows_in = tuple(jnp.asarray(rows) for rows, _ in staged)
+        masks_in = tuple(jnp.asarray(mask) for _, mask in staged)
+        jax.block_until_ready((rows_in, masks_in))
+        span.set(h2d_bytes=sum(rows.nbytes + mask.nbytes for rows, mask in staged))
 
-    fn = distributed_program(
-        query, plan, mesh, axis_name, cap_factor, route_cap_factor
-    )
-    rows_in = tuple(jnp.asarray(padded[nm]) for nm in rel_order)
-    masks_in = tuple(jnp.asarray(masks[nm]) for nm in rel_order)
-    counts, checksum, comm, loads, overflow = fn(rows_in, masks_in)
-    loads = np.asarray(loads)[:k]
-    return JoinResult(
-        count=int(np.asarray(counts).astype(np.int64).sum()),
-        checksum=int(np.uint32(np.int64(checksum) & 0xFFFFFFFF)),
-        comm_tuples={nm: int(c) for nm, c in zip(rel_order, np.asarray(comm))},
-        reducer_loads=loads,
-        overflow=int(overflow),
-    )
+    with obs.span("shuffle.lower", cat="job"):
+        program = distributed_program(
+            query, plan, mesh, axis_name, cap_factor, route_cap_factor
+        ).lower(rows_in, masks_in).compile()
+
+    with obs.span("shuffle.execute", cat="job"):
+        outs = jax.block_until_ready(program(rows_in, masks_in))
+
+    with obs.span("shuffle.fetch", cat="job") as span:
+        counts, checksum, comm, loads, overflow = (np.asarray(x) for x in outs)
+        result = JoinResult(
+            count=int(counts.astype(np.int64).sum()),
+            checksum=int(np.uint32(np.int64(checksum) & 0xFFFFFFFF)),
+            comm_tuples={nm: int(c) for nm, c in zip(rel_order, comm)},
+            reducer_loads=loads[:k],
+            overflow=int(overflow),
+        )
+        span.set(
+            d2h_bytes=sum(x.nbytes for x in (counts, checksum, comm, loads, overflow)),
+            comm_tuples=result.total_comm,
+            max_load=result.max_load,
+            total_load=int(result.reducer_loads.astype(np.int64).sum()),
+            reducers=k,
+            overflow=result.overflow,
+        )
+    return result

@@ -1,4 +1,5 @@
 """Integration tests: the JAX MapReduce join engine vs the host oracle."""
+import json
 import os
 import subprocess
 import sys
@@ -223,6 +224,85 @@ def test_distributed_single_device_matches_oracle():
     count, checksum, _, _ = oracle_join(two_way(), data)
     assert res.overflow == 0
     assert (res.count, res.checksum) == (count, checksum)
+
+
+# the batch entry with its tracing off, defaulted and on, on n host devices:
+# the plan, the result and the span names of each, as JSON
+_OBS_SNIPPET = r"""
+import json, os, sys
+n = int(sys.argv[1])
+os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+import jax
+import numpy as np
+from repro.core import plan_shares_skew, two_way
+from repro.data import paper_2way
+from repro.mapreduce import run_distributed
+from repro.obs import Observability, ObsPolicy
+
+assert len(jax.devices()) == n, jax.devices()
+data = paper_2way(np.random.default_rng(5), n_r=3000, n_s=600, domain=2000)
+out = {}
+for mode in ("default", "off", "on"):
+    kw = {} if mode == "default" else {"obs": Observability(ObsPolicy(trace=mode == "on"))}
+    plan = plan_shares_skew(two_way(), data, q=200, **kw)
+    res = run_distributed(two_way(), data, plan, cap_factor=4.0, route_cap_factor=4.0, **kw)
+    out[mode] = {
+        "plan": [
+            plan.describe(),
+            [sorted(r.solution.int_shares.items()) for r in plan.residuals],
+            [r.reducer_offset for r in plan.residuals],
+            {a: v.tolist() for a, v in plan.hh_values.items()},
+        ],
+        "result": [res.count, res.checksum, res.comm_tuples, res.reducer_loads.tolist(), res.overflow],
+        "spans": kw["obs"].tracer.span_names() if mode == "on" else [],
+    }
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def obs_modes():
+    """Device count -> the snippet's output, one subprocess per count (the
+    host device count is fixed when XLA starts)."""
+    done = {}
+
+    def get(n_devices: int) -> dict:
+        if n_devices not in done:
+            proc = subprocess.run(
+                [sys.executable, "-c", _OBS_SNIPPET, str(n_devices)],
+                capture_output=True,
+                text=True,
+                timeout=600,
+                env={
+                    "PYTHONPATH": "src",
+                    "PATH": "/usr/bin:/bin:/usr/local/bin",
+                    "JAX_PLATFORMS": "cpu",
+                },
+                cwd=Path(__file__).resolve().parents[1],
+            )
+            assert proc.returncode == 0, proc.stderr[-3000:]
+            done[n_devices] = json.loads(proc.stdout.splitlines()[-1])
+        return done[n_devices]
+
+    return get
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_batch_entry_is_bit_identical_with_tracing_on_and_off(obs_modes, n_devices):
+    """``plan_shares_skew`` and ``run_distributed`` give the same plan and
+    the same ``JoinResult`` with ``obs`` defaulted, off and tracing, and
+    the traced run opens the planner's and the shuffle's spans."""
+    out = obs_modes(n_devices)
+    assert out["on"]["plan"] == out["off"]["plan"] == out["default"]["plan"]
+    assert out["on"]["result"] == out["off"]["result"] == out["default"]["result"]
+    data = paper_2way(np.random.default_rng(5), n_r=3000, n_s=600, domain=2000)
+    count, checksum, _, _ = oracle_join(two_way(), data)
+    assert out["on"]["result"][:2] == [count, checksum]
+    assert out["on"]["result"][-1] == 0
+    assert out["on"]["spans"] == [
+        "plan.detect", "plan.solve",
+        "shuffle.stage", "shuffle.lower", "shuffle.execute", "shuffle.fetch",
+    ]
 
 
 def test_speculative_join_matches_plain():

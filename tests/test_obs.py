@@ -437,3 +437,43 @@ def test_spans_appear_in_the_profiler_trace(tmp_path):
         assert isinstance(stats["span_id"], str) and stats["span_id"].startswith(f"{stats['batch']}:")
     # the mirror never takes the names a caller's own annotations use
     assert not {"ingest", "window"} & other
+
+
+def test_batch_entry_spans_nest_under_the_caller_and_carry_counts():
+    """The planner's and the shuffle's spans open under the caller's span,
+    in the order the job runs them, with the counts the benchmark reads;
+    ``comm_tuples`` is the result's total communication."""
+    from repro.core import plan_shares_skew
+    from repro.data import paper_2way
+
+    obs = Observability(ObsPolicy(trace=True))
+    obs.tracer.set_batch(4)
+    data = paper_2way(np.random.default_rng(6), n_r=2000, n_s=400, domain=1500)
+    with obs.span("job", cat="job"):
+        plan = plan_shares_skew(two_way(), data, q=200, obs=obs)
+        res = run_distributed(two_way(), data, plan, cap_factor=4.0, route_cap_factor=4.0, obs=obs)
+    events = [e for e in obs.tracer.events if e["ph"] == "X"]
+    by_name = {e["name"]: e for e in events}
+    phases = ["plan.detect", "plan.solve",
+              "shuffle.stage", "shuffle.lower", "shuffle.execute", "shuffle.fetch"]
+    assert [e["name"] for e in events] == phases + ["job"]
+    root = by_name["job"]["args"]["span_id"]
+    for a, b in zip(phases, phases[1:]):
+        assert by_name[a]["ts"] + by_name[a]["dur"] <= by_name[b]["ts"]
+    for name in phases:
+        assert by_name[name]["args"]["parent"] == root
+        assert by_name[name]["args"]["batch"] == 4
+
+    args = {name: by_name[name]["args"] for name in phases}
+    assert args["plan.detect"]["heavy_hitters"] == sum(len(v) for v in plan.hh_values.values()) > 0
+    assert args["plan.solve"]["residuals"] == len(plan.residuals) == 2
+    assert args["plan.solve"]["reducers"] == plan.total_reducers
+    # one device: no padding; int32 rows and a bool mask per relation
+    assert args["shuffle.stage"]["h2d_bytes"] == sum(9 * len(rows) for rows in data.values())
+    fetch = args["shuffle.fetch"]
+    assert fetch["comm_tuples"] == sum(res.comm_tuples.values()) > 0
+    assert fetch["max_load"] == res.max_load
+    assert fetch["total_load"] == int(res.reducer_loads.sum())
+    assert fetch["reducers"] == plan.total_reducers
+    assert fetch["overflow"] == res.overflow == 0
+    assert fetch["d2h_bytes"] > 0
